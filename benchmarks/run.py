@@ -16,8 +16,11 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run a single bench: "

@@ -43,7 +43,7 @@ RULES = {
     "STR005": "dependency category derived from the traced jaxpr "
               "disagrees with tuning.workload.classify_workload",
     "KRN001": "BlockSpec/grid inconsistent with the wrapper's declared "
-              "operand shapes (rank, arity, divisibility)",
+              "operand shapes (rank, arity, divisibility, TPU tiling)",
     "KRN002": "scalar-prefetch operand never used as an index by any "
               "BlockSpec index_map",
     "KRN003": "quant kernel dtype contract broken against quant.py "

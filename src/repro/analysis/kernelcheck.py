@@ -9,9 +9,11 @@ wrapper-declared operand shapes fails here before it fails on a TPU.
 Rules:
 
 * **KRN001** — BlockSpec/grid inconsistency: block rank vs operand rank,
-  block dims that don't divide the operand dims, index maps whose arity
-  doesn't match ``len(grid) + num_scalar_prefetch`` or that return the
-  wrong number of coordinates.
+  block dims that don't divide the operand dims, last two block dims that
+  break the TPU tiling rule (divisible by 8 and 128, or equal to the
+  operand's dims — Mosaic refuses anything else at compile time), index
+  maps whose arity doesn't match ``len(grid) + num_scalar_prefetch`` or
+  that return the wrong number of coordinates.
 * **KRN002** — a scalar-prefetch operand no index map ever reads: the
   kernel DMAs the scalars every step and then ignores them (a dead
   prefetch is almost always a page-table wiring bug).
@@ -27,6 +29,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import math
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +88,15 @@ def _check_spec(name: str, what: str, spec, op_shape, grid, n_prefetch: int,
                 "KRN001", target,
                 f"block dim {d} = {b} does not tile operand dim {s}",
                 "kernel"))
+    if len(block) >= 2:
+        for d, tile in ((len(block) - 2, 8), (len(block) - 1, 128)):
+            b, s = block[d], op_shape[d]
+            if b is not None and b % tile and b != s:
+                findings.append(Finding(
+                    "KRN001", target,
+                    f"block dim {d} = {b} is neither a multiple of {tile} "
+                    f"nor the whole operand dim {s} (TPU tiling rule)",
+                    "kernel"))
     sig = inspect.signature(spec.index_map)
     arity = len(sig.parameters)
     want = len(grid) + n_prefetch
@@ -143,7 +155,7 @@ def check_layout(name: str, meta: dict) -> list[Finding]:
 def check_quant_contract() -> list[Finding]:
     """KRN003: the quant kernels accept pools in ``quant.storage_dtype``
     with per-(page, kv-head) f32 scales and return the query dtype."""
-    from repro.kernels import ops, quant
+    from repro.kernels import ops, paged_attention, quant
 
     findings: list[Finding] = []
     b, h, hkv, hd, nb, bs = 2, 4, 2, 8, 9, 8
@@ -180,6 +192,18 @@ def check_quant_contract() -> list[Finding]:
                 "KRN003", f"quant.scales_of[{kind}]",
                 f"per-page scale is {sc.shape} {sc.dtype}, kernel expects "
                 "(kv_heads,) float32 per page", "kernel"))
+    # The scale operand the kernel's BlockSpec tiles must be a free view
+    # of the (num_blocks, kv_heads) scale pool: same page axis, kv heads
+    # last, nothing else but unit dims.
+    sp = paged_attention.build_specs(b, hkv, h // hkv, hd, nb, bs, 4,
+                                     quantized=True)
+    for i in (3, 4):
+        view = tuple(sp["operands"][i])
+        if (view[0], view[-1]) != (nb, hkv) or math.prod(view) != nb * hkv:
+            findings.append(Finding(
+                "KRN003", f"paged_attention:in[{i}]",
+                f"kernel tiles scales as {view}, not a view of the "
+                f"({nb}, {hkv}) per-page scale pool", "kernel"))
     return findings
 
 

@@ -6,7 +6,7 @@ Layer unit (8 layers, repeated 9x): attention at index 3, all others Mamba;
 MoE replaces the dense MLP on every other layer (odd indices) -> 4 MoE
 layers per unit, 36 total.  Attention layers carry no positional encoding
 (the Mamba layers provide position information).  We use our Mamba2/SSD
-mixer where the original uses Mamba-1 (noted in DESIGN.md): same state-size
+mixer where the original uses Mamba-1: same state-size
 asymptotics, TPU-friendlier chunked form.
 """
 

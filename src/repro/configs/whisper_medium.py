@@ -4,7 +4,7 @@ embeddings (B, 1500, d_model)) [arXiv:2212.04356; unverified].
 
 Notes: the real model caps decoder positions at 448; the assigned
 prefill_32k/decode_32k shapes are synthetic stress configs exercised on the
-backbone only (documented in DESIGN.md §Arch-applicability).
+backbone only.
 """
 
 from repro.configs.base import LayerSpec, ModelConfig, smoke_reduce

@@ -1,4 +1,4 @@
-"""Cluster-level streaming: collective <-> compute overlap (DESIGN.md S3 L3).
+"""Cluster-level streaming: collective <-> compute overlap.
 
 At pod scale the "transfer" stage of the paper's pipeline is the collective.
 A blocking ``all-gather -> matmul`` serializes the two stages exactly like the
@@ -21,28 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-# jax.shard_map landed in 0.6; older releases only have the experimental path.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
-    """Mark ``x`` as varying over ``axis_name`` (shard_map VMA bookkeeping)."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, (axis_name,))
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis_name,), to="varying")  # older spelling
-    return x  # pre-VMA JAX: no bookkeeping needed
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size; ``lax.axis_size`` only exists on newer JAX."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # constant-folds to the static size
-
 
 # ----------------------------------------------------------------------------
 # Blocking references (single-stream analogue).
@@ -84,12 +62,12 @@ def ag_matmul_ring(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     (``ppermute``).  Same math as ``ag_matmul_reference``; the collective is
     decomposed into P-1 overlappable hops.
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m_local = x.shape[0]
     y = jnp.zeros((m_local * p, w.shape[1]), dtype=jnp.result_type(x.dtype, w.dtype))
     # The accumulator is device-varying (each device fills different rows).
-    y = _pvary(y, axis_name)
+    y = jax.lax.pcast(y, (axis_name,), to="varying")
     perm = [(i, (i - 1) % p) for i in range(p)]  # send to the left neighbour
 
     def step(i, carry):
@@ -113,7 +91,7 @@ def rs_matmul_ring(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     steps every device holds the fully-reduced rows it owns.  The accumulator
     hop overlaps the next chunk's matmul.
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m_full = x.shape[0]
     assert m_full % p == 0, "rows must divide the axis size"
@@ -151,7 +129,7 @@ def make_sharded_ag_matmul(
     fn = ag_matmul_ring if ring else ag_matmul_reference
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name, None), P(None, axis_name)),
         out_specs=P(None, axis_name),
@@ -169,7 +147,7 @@ def make_sharded_rs_matmul(
     fn = rs_matmul_ring if ring else rs_matmul_reference
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis_name), P(axis_name, None)),
         out_specs=P(axis_name, None),

@@ -35,7 +35,7 @@ import dataclasses
 import enum
 import math
 import re
-from typing import Mapping, Sequence
+from typing import Mapping
 
 # ----------------------------------------------------------------------------
 # Hardware model (TPU v5e per-chip numbers from the assignment).
@@ -344,20 +344,9 @@ def roofline_from_cost(
     )
 
 
-def cost_analysis_scalars(cost: Mapping[str, float] | Sequence[Mapping[str, float]]) -> tuple[float, float]:
+def cost_analysis_scalars(cost: Mapping[str, float]) -> tuple[float, float]:
     """Extract (flops, bytes accessed) from compiled.cost_analysis()."""
-    if isinstance(cost, Sequence) and not isinstance(cost, (str, bytes)):
-        cost = cost[0] if cost else {}
-    flops = float(cost.get("flops", 0.0))
-    nbytes = float(cost.get("bytes accessed", 0.0))
-    if nbytes == 0.0:
-        # Older XLA splits per-operand: sum 'bytes accessed{N}' entries.
-        nbytes = sum(
-            float(v)
-            for k, v in cost.items()
-            if isinstance(k, str) and k.startswith("bytes accessed")
-        )
-    return flops, nbytes
+    return float(cost.get("flops", 0.0)), float(cost.get("bytes accessed", 0.0))
 
 
 def model_flops(n_params: float, n_tokens: float, *, backward: bool = True) -> float:

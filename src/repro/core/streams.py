@@ -3,7 +3,7 @@
 The paper's streaming flow is: partition the workload into tasks; spawn
 streams; overlap the H2D stage of task i+1 with the KEX stage of task i.  In
 JAX there is no user-visible stream object, so "multiple streams" shows up at
-three levels (see DESIGN.md S3):
+three levels:
 
   * **Device level** (inside jit): ``stream_map`` partitions the leading axis
     into tasks and executes them as a sequential grid (``lax.map`` /

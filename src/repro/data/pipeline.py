@@ -1,7 +1,7 @@
 """Data pipeline with host-side multi-stream prefetch.
 
 ``PrefetchIterator`` is the paper's H2D/KEX overlap at the training-loop
-level (DESIGN.md §3, level L1): worker threads produce and transfer the next
+level (level L1): worker threads produce and transfer the next
 ``depth`` batches (H2D stage) while the accelerator runs the current step
 (KEX stage).  ``depth`` is the stream count; ``depth=0`` degrades to the
 paper's single-stream stage-by-stage execution, which is what

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _pallas_compat as _plc
-
 NEG_INF = -1e30
 
 
@@ -177,7 +175,7 @@ def flash_attention_kernel(
         out_specs=sp["out_specs"],
         out_shape=jax.ShapeDtypeStruct(sp["out_shape"], q.dtype),
         scratch_shapes=sp["scratch_shapes"],
-        compiler_params=_plc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -10,8 +10,7 @@ each kernel invocation transforms an independent length-``block`` segment
 (in-block butterfly stages run entirely in VMEM), and the cross-block stages
 become a second streamed pass over the transposed layout — the "redundant
 boundary transfer" of the paper becomes a transpose between two clean
-streams, which is the TPU-idiomatic way to eliminate the RAR dependency
-(DESIGN.md §3).
+streams, which is the TPU-idiomatic way to eliminate the RAR dependency.
 
 The grid dimension is the stream: block i+1's DMA overlaps block i's
 butterflies.
@@ -25,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import _pallas_compat as _plc
 
 
 def _fwt_block_kernel(x_ref, o_ref, *, block: int):
@@ -62,7 +59,7 @@ def fwt_block(
         in_specs=[pl.BlockSpec((rt, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rt, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, block), x.dtype),
-        compiler_params=_plc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

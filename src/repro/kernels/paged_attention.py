@@ -8,19 +8,23 @@ block-fetch DMA, which Mosaic pipelines against the previous page's MXU
 compute (the paper's stream overlap, with pages as the Independent transfer
 tasks).
 
-Grid: (batch, kv_heads, n_pages) — the page stream is the innermost
-(sequential) dimension; the online-softmax state (m, l, acc) lives in VMEM
-scratch across it, exactly like ``flash_attention``'s KV stream.  Pages
-fully beyond a row's ``cur_len`` (or outside its sliding window) skip
-compute via ``pl.when``; in-page masking is positional (iota vs ``cur_len``),
-so trash-page garbage never contributes.
+Grid: (batch, n_pages) — the page stream is the innermost (sequential)
+dimension.  Each step fetches one whole physical page with all its KV heads
+(block ``(1, block_size, Hkv, hd)``: the last two block dims equal the
+pool's, which is what Mosaic's (8, 128) tiling rule accepts), so a page is
+read once per (row, page) rather than once per KV head.  A static loop over
+the KV heads runs inside the body; each head keeps its own online-softmax
+state (m, l, acc) in VMEM scratch across the page stream, exactly like
+``flash_attention``'s KV stream.  Pages fully beyond a row's ``cur_len`` (or
+outside its sliding window) skip compute via ``pl.when``; in-page masking is
+positional (iota vs ``cur_len``), so trash-page garbage never contributes.
 
 ``q_len > 1`` (speculative multi-token decode) folds the query block into
-the row dimension: the kernel scores ``q_len * g`` query rows per (batch,
-kv-head) cell, with row ``r``'s query sitting at absolute position
-``cur_len + r // g`` — the causal-within-the-block mask of the verify step.
-A page is skipped only when *every* query in the block masks it (the
-youngest query bounds the causal cut, the oldest bounds the window cut).
+the row dimension: the kernel scores ``q_len * g`` query rows per KV head,
+with row ``r``'s query sitting at absolute position ``cur_len + r // g`` —
+the causal-within-the-block mask of the verify step.  A page is skipped
+only when *every* query in the block masks it (the youngest query bounds
+the causal cut, the oldest bounds the window cut).
 """
 
 from __future__ import annotations
@@ -32,21 +36,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _pallas_compat as _plc
-
 NEG_INF = -1e30
 
 
 def _paged_kernel(
     pt_ref,  # SMEM (B, n_pages) int32: scalar-prefetched page table
     cl_ref,  # SMEM (B,) int32: per-row current position
-    q_ref,  # (1, 1, q_len * g, hd)
-    k_ref,  # (1, bs, 1, hd): one physical page of this kv head
-    v_ref,  # (1, bs, 1, hd)
+    q_ref,  # (1, Hkv, q_len * g, hd)
+    k_ref,  # (1, bs, Hkv, hd): one physical page, all kv heads
+    v_ref,  # (1, bs, Hkv, hd)
     *rest,  # quantized: (ks_ref, vs_ref, o_ref, m, l, acc) — the per-page
-    # per-head f32 scales ride the same scalar-prefetched indexing as the
-    # page itself, so dequantization is fused into the block compute (the
-    # pool's narrow codes are what the DMA moves); else (o_ref, m, l, acc)
+    # per-head f32 scales, (1, 1, Hkv), ride the same scalar-prefetched
+    # indexing as the page itself, so dequantization is fused into the
+    # block compute (the pool's narrow codes are what the DMA moves); else
+    # (o_ref, m, l, acc)
     n_pages: int,
     block_size: int,
     q_len: int,
@@ -62,7 +65,8 @@ def _paged_kernel(
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    hkv = k_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -80,45 +84,49 @@ def _paged_kernel(
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]  # (q_len * g, hd)
-        k = k_ref[0, :, 0, :]  # (bs, hd)
-        v = v_ref[0, :, 0, :]  # (bs, hd)
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)
-
-        rows, bs = s.shape
+        rows = q_ref.shape[2]
         pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, bs), 1)
+            jnp.int32, (rows, block_size), 1)
         # Row r is query r // group at absolute position cur + r // group:
         # causal within the draft block, per query.
-        qpos = cur + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
+        qpos = cur + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_size), 0) // group
         ok = pos <= qpos
         if window > 0:
             ok = ok & (qpos - pos < window)
-        s = jnp.where(ok, s, NEG_INF)
+        if quantized:
+            ks = ks_ref[0]  # (1, Hkv)
+            vs = vs_ref[0]
+        for h in range(hkv):  # static: one GQA group per kv head
+            q = q_ref[0, h]  # (rows, hd)
+            k = k_ref[0, :, h, :]  # (bs, hd)
+            v = v_ref[0, :, h, :]
+            if quantized:
+                k = k.astype(jnp.float32) * ks[:, h:h + 1]
+                v = v.astype(jnp.float32) * vs[:, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if softcap > 0.0:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(ok, s, NEG_INF)
 
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_old - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = alpha[:, None] * acc_ref[...] + pv
-        m_ref[...] = m_new
+            m_old = m_ref[h]  # (rows, 1)
+            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_old - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[h] = alpha * acc_ref[h] + pv
+            m_ref[h] = m_new
 
     @pl.when(j == n_pages - 1)
     def _flush():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
@@ -135,35 +143,35 @@ def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
     ``in_specs``, prefetch excluded).
     """
     in_specs = [
-        pl.BlockSpec((1, 1, rows, hd),
-                     lambda bb, hh, jj, pt, cl: (bb, hh, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd),
-                     lambda bb, hh, jj, pt, cl: (pt[bb, jj], 0, hh, 0)),
-        pl.BlockSpec((1, bs, 1, hd),
-                     lambda bb, hh, jj, pt, cl: (pt[bb, jj], 0, hh, 0)),
+        pl.BlockSpec((1, hkv, rows, hd),
+                     lambda bb, jj, pt, cl: (bb, 0, 0, 0)),
+        pl.BlockSpec((1, bs, hkv, hd),
+                     lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0, 0)),
+        pl.BlockSpec((1, bs, hkv, hd),
+                     lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0, 0)),
     ]
     operands = [(b, hkv, rows, hd), (nb, bs, hkv, hd), (nb, bs, hkv, hd)]
     if quantized:
-        # The scale rides the page's scalar-prefetched index: one (1, 1)
-        # block of the (num_blocks, Hkv) scale pool per grid step.
+        # The scale rides the page's scalar-prefetched index: one (1, 1,
+        # Hkv) block of the (num_blocks, 1, Hkv) scale view per grid step.
         in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda bb, hh, jj, pt, cl: (pt[bb, jj], hh)),
-            pl.BlockSpec((1, 1),
-                         lambda bb, hh, jj, pt, cl: (pt[bb, jj], hh)),
+            pl.BlockSpec((1, 1, hkv),
+                         lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0)),
+            pl.BlockSpec((1, 1, hkv),
+                         lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0)),
         ]
-        operands += [(nb, hkv), (nb, hkv)]
+        operands += [(nb, 1, hkv), (nb, 1, hkv)]
     return dict(
-        grid=(b, hkv, n_pages),
+        grid=(b, n_pages),
         num_scalar_prefetch=2,
         prefetch_index_operands=(0,),  # page table; cur_len is body-read
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, rows, hd), lambda bb, hh, jj, pt, cl: (bb, hh, 0, 0)),
+            (1, hkv, rows, hd), lambda bb, jj, pt, cl: (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, hd), jnp.float32),
         ],
         operands=operands,
         out_shape=(b, hkv, rows, hd),
@@ -177,22 +185,22 @@ KERNEL_META = {
         build=build_specs,
         lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8, n_pages=4,
                          quantized=False),
-        grid_dims=("batch", "kv_heads", "pages"),
-        sequential_dim=2,
+        grid_dims=("batch", "pages"),
+        sequential_dim=1,
     ),
     "paged_attention_multi": dict(
         build=build_specs,
         lint_shapes=dict(b=2, hkv=2, rows=12, hd=8, nb=9, bs=8, n_pages=4,
                          quantized=False),
-        grid_dims=("batch", "kv_heads", "pages"),
-        sequential_dim=2,
+        grid_dims=("batch", "pages"),
+        sequential_dim=1,
     ),
     "paged_attention_quant": dict(
         build=build_specs,
         lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8, n_pages=4,
                          quantized=True),
-        grid_dims=("batch", "kv_heads", "pages"),
-        sequential_dim=2,
+        grid_dims=("batch", "pages"),
+        sequential_dim=1,
     ),
 }
 
@@ -226,7 +234,9 @@ def _paged_call(
     inputs = [page_table.astype(jnp.int32), cur_len.astype(jnp.int32), qr,
               k_pool, v_pool]
     if quantized:
-        inputs += [k_scale, v_scale]
+        # A free view of the quant.py scale pool whose last two block dims
+        # are whole (1, Hkv) — the tiling rule's "equal to the array" case.
+        inputs += [k_scale.reshape(nb, 1, hkv), v_scale.reshape(nb, 1, hkv)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=sp["num_scalar_prefetch"],
@@ -240,8 +250,8 @@ def _paged_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(sp["out_shape"], qr.dtype),
-        compiler_params=_plc.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*inputs)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _pallas_compat as _plc
-
 NEG = -1e30
 
 
@@ -120,7 +118,7 @@ def ssd_chunk_kernel(
         out_specs=pl.BlockSpec((1, chunk, p), lambda b, t: (b, t, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, p), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_plc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

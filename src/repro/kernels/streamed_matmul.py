@@ -4,7 +4,7 @@ The grid + BlockSpec index maps below ARE the multiple-stream mechanism at
 the chip level: Mosaic turns the sequential (i, j, k) task grid into an
 HBM->VMEM DMA pipeline where block (i, j, k+1)'s transfer overlaps block
 (i, j, k)'s MXU compute — exactly the paper's "H2D of task t+1 overlaps KEX
-of task t" (DESIGN.md §3, level L2).
+of task t" (level L2).
 
 Block shapes are chosen so the working set (x-block + y-block + f32
 accumulator) fits VMEM and the MXU dims are multiples of 128.
@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import _pallas_compat as _plc
 
 
 def _mm_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
@@ -69,7 +67,7 @@ def streamed_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.result_type(x.dtype, y.dtype)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_plc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -165,7 +165,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool):
             cfg.active_param_count(), shape.global_batch, backward=False)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()
@@ -206,10 +206,10 @@ def analyse(compiled, meta: dict[str, Any]) -> dict[str, Any]:
         "collective_bytes": cost.collective_bytes,
         "collective_breakdown": {
             k: v for k, v in cost.collective_by_op.items() if v},
-        "mem_argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-        "mem_output_bytes": getattr(mem, "output_size_in_bytes", None),
-        "mem_temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-        "mem_generated_code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
+        "mem_argument_bytes": mem.argument_size_in_bytes,
+        "mem_output_bytes": mem.output_size_in_bytes,
+        "mem_temp_bytes": mem.temp_size_in_bytes,
+        "mem_generated_code_bytes": mem.generated_code_size_in_bytes,
         "t_compute_s": terms.compute,
         "t_memory_s": terms.memory,
         "t_collective_s": terms.collective,

@@ -3,6 +3,11 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --requests 4 --prompt-len 128 --new-tokens 16
 
+The smoke preset of the arch is served by default; ``--full`` serves its
+published-width config in its own dtypes (qwen3-4b: 36 layers, d_model
+2560, bf16, about 8 GB of weights — one TPU v5e chip), with seeded random
+weights built on the device.
+
 Every servable arch — decoder-only transformers, SSMs (mamba2/jamba), and
 encoder-decoder (whisper, per-request ``enc_inputs``) — goes through
 ``StreamedBatchEngine`` (request queue + slot pool, chunked prefill
@@ -19,14 +24,19 @@ import jax
 import numpy as np
 
 import repro.configs as configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.runtime.serving import (ServeConfig, ServingEngine,
                                    StreamedBatchEngine)
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=configs.list_archs())
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published-width config (default: the "
+                         "smoke preset)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -114,8 +124,12 @@ def main() -> None:
     if args.kv_dtype != "fp32" and not args.paged:
         ap.error("--kv-dtype quantizes the paged pool; it requires --paged")
 
-    cfg = configs.get_smoke_config(args.arch)
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    cfg = configs.get_config(args.arch) if args.full else \
+        configs.get_smoke_config(args.arch)
+    # Jitted, so the weights are made on the device without host copies or
+    # per-op f32 temporaries.
+    params = jax.jit(T.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
     max_seq = args.prompt_len + cfg.prefix_len + args.new_tokens
     if args.paged:  # pages must tile the cache
         max_seq = -(-max_seq // args.block_size) * args.block_size

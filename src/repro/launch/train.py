@@ -3,8 +3,8 @@
 CPU-scale run (reduced config of the arch family):
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --steps 50
 
-Production mesh run (on a real pod; here the mesh falls back to the host
-devices):
+Production mesh run (on a real pod; the mesh is built over the devices
+present):
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --full \
         --mesh-data 16 --mesh-model 16
 """
@@ -14,11 +14,13 @@ from __future__ import annotations
 import argparse
 
 import repro.configs as configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.runtime.trainer import TrainConfig, Trainer
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=configs.list_archs())
     ap.add_argument("--full", action="store_true",

@@ -6,7 +6,7 @@ Conventions:
     the loss accumulate in fp32.
   * the chunked cross-entropy streams over sequence chunks so the full
     (B, S, V) logits tensor is never materialized -- an Independent-task
-    stream (see repro.core.streams / DESIGN.md S2).
+    stream (see repro.core.streams).
 """
 
 from __future__ import annotations

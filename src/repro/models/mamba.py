@@ -1,7 +1,7 @@
 """Mamba2 (SSD, state-space duality) block — arXiv:2405.21060.
 
-The chunked SSD algorithm *is* the paper's true-dependent streaming
-(DESIGN.md S4): the sequence is partitioned into chunks (tasks); intra-chunk
+The chunked SSD algorithm *is* the paper's true-dependent streaming:
+the sequence is partitioned into chunks (tasks); intra-chunk
 compute is independent dense work, while the inter-chunk SSM state is a RAW
 dependency handed from task to task — a 1-D wavefront.  We execute it with a
 ``lax.scan`` over chunks (see ``repro.core.streams.stream_scan``), so each

@@ -16,18 +16,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def current_mesh():
-    """The mesh installed by ``with mesh:``, or None."""
-    try:
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m.empty:
-            return None
-        return m
-    except Exception:
-        return None
+    """The mesh installed by ``jax.set_mesh(mesh)``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def batch_axes_for(b: int, sizes: dict[str, int]):
@@ -44,7 +35,7 @@ def shard_batch(x: jax.Array) -> jax.Array:
     mesh = current_mesh()
     if mesh is None:
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     ax = batch_axes_for(x.shape[0], sizes)
     return jax.lax.with_sharding_constraint(
         x, P(ax, *([None] * (x.ndim - 1))))
@@ -55,7 +46,7 @@ def shard_spec(x: jax.Array, *axes) -> jax.Array:
     mesh = current_mesh()
     if mesh is None:
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
 
     def ok(a, dim):
         if a is None:

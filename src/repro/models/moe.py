@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN: top-k routing with capacity + one-hot dispatch.
 
-Expert dispatch is Independent-task streaming (DESIGN.md S4): tokens are
+Expert dispatch is Independent-task streaming: tokens are
 partitioned across experts, each expert's batch is an independent task, and
 with experts sharded over the ``model`` mesh axis the dispatch/combine
 einsums lower to all-to-alls whose transfer overlaps expert compute.

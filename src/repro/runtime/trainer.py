@@ -1,6 +1,6 @@
 """Trainer: the end-to-end training loop wiring every streaming layer together.
 
-Streams in play per step (DESIGN.md §2):
+Streams in play per step:
   L1  host batch prefetch (PrefetchIterator, depth = stream count),
   L1' async checkpoint D2H,
   L3  grad-accumulation microbatch streaming inside train_step,
@@ -10,6 +10,7 @@ latest checkpoint, straggler logging, elastic re-mesh on restore.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable
@@ -124,7 +125,8 @@ class Trainer:
         step_fn = self._jit_step()
         data = self._source(start_step)
         losses: list[float] = []
-        ctx = self.mesh if self.mesh is not None else _NullCtx()
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
+               else contextlib.nullcontext())
         t_start = time.perf_counter()
         with ctx:
             for step in range(start_step, self.tcfg.steps):
@@ -170,11 +172,3 @@ class Trainer:
             opt_state = jax.device_put(
                 opt_state, sharding.to_named(ospecs, self.mesh))
         return (params, opt_state), meta
-
-
-class _NullCtx:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False

@@ -75,6 +75,27 @@ def test_fixture_bad_blockspec_trips_krn001():
     assert _rules(findings) == {"KRN001"}
 
 
+def test_size_one_head_block_trips_krn001_tiling():
+    """A K/V block holding one KV head of a (pages, rows, Hkv, hd) pool
+    — (1, bs, 1, hd) — breaks the TPU tiling rule on its second-last dim,
+    which the TPU compiler refuses; the lint must flag it statically."""
+    from jax.experimental import pallas as pl
+
+    def build():
+        spec = pl.BlockSpec((1, 8, 1, 128), lambda b, h, j, pt: (pt[b, j], 0, h, 0))
+        return dict(grid=(2, 2, 4), num_scalar_prefetch=1,
+                    prefetch_index_operands=(0,), in_specs=[spec],
+                    out_specs=pl.BlockSpec((1, 1, 4, 128),
+                                           lambda b, h, j, pt: (b, h, 0, 0)),
+                    operands=[(9, 8, 2, 128)], out_shape=(2, 2, 4, 128))
+
+    findings = kernelcheck.check_layout(
+        "one_head_block", dict(build=build, lint_shapes={}))
+    assert _rules(findings) == {"KRN001"}
+    assert [f.target for f in findings] == ["one_head_block:in[0]"]
+    assert "tiling" in findings[0].message
+
+
 def test_fixture_refcount_leak_trips_pool001():
     kv = _small_pool()
     assert kv.alloc(0, 20)

@@ -28,7 +28,8 @@ class TestRingCollectives:
         out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import overlap
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 x = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
 w = jax.random.normal(jax.random.PRNGKey(1), (32, 48))
 for maker in (overlap.make_sharded_ag_matmul, overlap.make_sharded_rs_matmul):
@@ -47,7 +48,8 @@ print("OK")
         out = run_sub("""
 import jax, jax.numpy as jnp
 from repro.core import overlap, hloanalysis
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 x = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
 w = jax.random.normal(jax.random.PRNGKey(1), (32, 48))
 costs = {}
@@ -69,13 +71,14 @@ class TestElasticResharding:
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.launch.mesh import make_mesh
 ck = Checkpointer({str(tmp_path)!r})
-mesh_a = jax.make_mesh((8, 1), ("data", "model"))
+mesh_a = make_mesh((8, 1), ("data", "model"))
 tree = {{"w": jax.device_put(jnp.arange(64.0).reshape(8, 8),
         NamedSharding(mesh_a, P("data", None)))}}
 ck.save(0, tree, blocking=True)
 # restart on a DIFFERENT mesh shape (elastic re-mesh: lost half the nodes)
-mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+mesh_b = make_mesh((2, 2), ("data", "model"))
 shardings = {{"w": NamedSharding(mesh_b, P("data", "model"))}}
 got, meta = ck.restore(shardings=shardings)
 assert np.allclose(np.asarray(got["w"]), np.arange(64.0).reshape(8, 8))
@@ -93,6 +96,7 @@ class TestShardedTrainStep:
 import jax, jax.numpy as jnp, numpy as np
 import repro.configs as C
 from repro.launch import sharding, steps
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.models import transformer as T
 cfg = C.get_smoke_config("qwen3-4b")
@@ -103,11 +107,11 @@ batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.voc
 fn = steps.make_train_step(cfg, opt_cfg, accum=2)
 p1, o1, m1 = jax.jit(fn)(params, opt, batch)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 pshape = jax.eval_shape(lambda: params)
 pspecs = sharding.param_specs(pshape, mesh)
 ospecs = sharding.opt_state_specs(pspecs)
-with mesh:
+with jax.set_mesh(mesh):
     p_sh = jax.device_put(params, sharding.to_named(pspecs, mesh))
     o_sh = jax.device_put(opt, sharding.to_named(ospecs, mesh))
     p2, o2, m2 = jax.jit(fn,
@@ -130,10 +134,11 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 import repro.configs as C
 from repro.launch import sharding, steps
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.models import transformer as T
 cfg = C.get_smoke_config("mixtral-8x7b")
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 params_shape = jax.eval_shape(lambda k: T.init_params(cfg, k), jax.random.PRNGKey(0))
 pspecs = sharding.param_specs(params_shape, mesh)
 params_in = sharding.shaped(params_shape, pspecs, mesh)
@@ -146,7 +151,7 @@ bspecs = sharding.batch_specs(bshapes, mesh)
 batch_in = sharding.shaped(bshapes, bspecs, mesh)
 fn = steps.make_train_step(cfg, opt_cfg, accum=2)
 metrics_specs = {k: P() for k in ("loss", "ce", "aux", "grad_norm", "lr")}
-with mesh:
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn,
         in_shardings=(sharding.to_named(pspecs, mesh),
                       sharding.to_named(ospecs, mesh),
@@ -157,6 +162,27 @@ with mesh:
         donate_argnums=(0, 1)).lower(params_in, opt_in, batch_in).compile()
 mem = compiled.memory_analysis()
 assert mem.temp_size_in_bytes > 0
+print("OK")
+""")
+        assert "OK" in out
+
+
+class TestChipSmokeShardedPhase:
+    def test_sharded_step_matches_local_on_four_devices(self):
+        """``chip_smoke.py --chips 4``'s phase on four virtual devices, in
+        bf16 as on the chip: the (data=2, model=2) step matches device 0."""
+        out = run_sub(f"""
+import dataclasses, importlib.util
+import jax, jax.numpy as jnp
+import repro.configs as C
+spec = importlib.util.spec_from_file_location(
+    "chip_smoke", {os.path.join(REPO, "chip_smoke.py")!r})
+cs = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(cs)
+cfg = dataclasses.replace(C.get_smoke_config(cs.ARCH),
+                          param_dtype=jnp.bfloat16, compute_dtype=jnp.bfloat16)
+res = cs.sharded_step_matches_local(cfg, jax.devices()[:4], seq=32)
+print(res)
 print("OK")
 """)
         assert "OK" in out

@@ -382,6 +382,8 @@ class TestSearch:
                 "--prefill-chunk", "8", "--max-batch", "2", "--paged",
                 "--autotune", "--tune-budget", "3",
                 "--tuning-db", str(db_path)]
+        # The launcher's persistent compile cache stays out of tests.
+        monkeypatch.setattr(serve_mod, "use_compile_cache", lambda: None)
         monkeypatch.setattr("sys.argv", argv)
         serve_mod.main()
         out = capsys.readouterr().out
