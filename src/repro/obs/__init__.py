@@ -1,7 +1,9 @@
 """Observability layer: trace spans, metrics, and overlap reconstruction.
 
-``Tracer`` records per-tick spans on the prefill/decode/transfer tracks
-(Chrome/Perfetto export); ``MetricsRegistry`` holds the engine's
+``Tracer`` records per-tick spans on the prefill/decode/transfer tracks,
+the host loop's phases and Python collections on the host track
+(Chrome/Perfetto export, mirrored into a recording ``jax.profiler``
+trace); ``MetricsRegistry`` holds the engine's
 counters and latency/transfer histograms behind one snapshot; ``overlap``
 turns the recorded timeline into a measured overlap efficiency and
 compares it with the R-gate's analytic prediction.  On top of those:
@@ -29,7 +31,7 @@ from .requests import (
     timelines_from_trace,
 )
 from .slo import SLOPolicy, score_timelines
-from .trace import TRACKS, Span, Tracer, read_trace, span_tree
+from .trace import TRACKS, Span, Tracer, read_trace
 
 __all__ = [
     "Histogram",
@@ -39,7 +41,6 @@ __all__ = [
     "Tracer",
     "TRACKS",
     "read_trace",
-    "span_tree",
     "measured_overlap",
     "predicted_overlap",
     "overlap_report",

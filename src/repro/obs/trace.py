@@ -8,34 +8,65 @@ as Chrome ``trace.json`` (``chrome://tracing`` / https://ui.perfetto.dev)
 so the overlap the engine achieves is literally viewable.
 
 Cost model: when ``enabled`` is False every hook is a single attribute
-check and the clock is never read (``t()`` returns 0, ``add()`` returns
-immediately); no buffer is allocated.  When enabled, a span is one tuple
-append — no I/O, no allocation beyond the record — so tracing is safe on
-the decode tick path.  The engine only ever calls plain methods on the
-tracer, never coerces device values, so instrumentation stays invisible
-to the ``@tick_path`` AST lint.
+check and the clock is never read (``begin()`` returns 0, ``add()``
+returns immediately); no buffer is allocated and no collection is
+recorded.  When enabled, a span costs two clock reads, one check that
+no profiler trace is recording, and one ``Span`` put in the ring: about
+2 µs on the host, no I/O.  The engine only ever calls plain methods on
+the tracer, never coerces device values, so instrumentation stays
+invisible to the ``@tick_path`` AST lint.
 
 Track names are the span taxonomy's first level:
 
 * ``prefill``  — admission windows and in-flight prefill chunks
 * ``decode``   — decode/spec ticks (host_fetch-bounded, so true latency)
 * ``transfer`` — page scatter/gather, evict/readmit staging, H2D prep
+* ``host``     — what the host loop does inside a tick or an admission
+  (``schedule``, ``page_fault``, ``stage``, ``dispatch``, ``fetch_wait``,
+  ``emit``) and every Python collection (``gc``)
+
+While a ``jax.profiler`` trace records, each span is also a profiler
+annotation of the same name, opened and closed where the span's stamps
+are taken, so the host's phases sit on the trace's host plane beside the
+device's operations.  The annotation class is looked up in
+``sys.modules``: nothing here imports jax, and a process that never
+imported it runs no profiler to mirror into.
 
 numpy/stdlib only; importable without jax.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import time
+import weakref
 from dataclasses import dataclass, field
-from typing import Any, Iterable
+from typing import Any
 
-__all__ = ["Span", "Tracer", "read_trace", "span_tree", "TRACKS"]
+__all__ = ["Span", "Tracer", "read_trace", "TRACKS"]
 
 #: Logical streams, in display order (tid in the Chrome export).
-TRACKS = ("prefill", "decode", "transfer")
+TRACKS = ("prefill", "decode", "transfer", "host")
+
+#: ``jaxlib``'s profiler annotation class, once jax has been imported.
+_TraceMe: Any = None
+
+
+def _find_traceme() -> Any:
+    global _TraceMe
+    mod = sys.modules.get("jaxlib._profiler")
+    if mod is not None:
+        _TraceMe = mod.TraceMe
+    return _TraceMe
+
+
+class _Mark(int):
+    """A span's start stamp that also holds the profiler annotation opened
+    with it; ``Tracer.add`` closes the annotation.  An annotation whose
+    span is never added closes when its mark is freed."""
 
 
 @dataclass(frozen=True)
@@ -62,12 +93,18 @@ class Tracer:
 
     Usage on an instrumented path::
 
-        t0 = tr.t()                 # 0 when disabled, never reads clock
+        t0 = tr.begin("decode_tick")   # 0 when disabled, never reads clock
         ... work ...
         tr.add("decode", "decode_tick", t0, tick=n, d2h_bytes=b)
 
-    ``add`` closes the span at the current clock.  ``instant`` records a
+    ``add`` closes the span at the current clock; the name given to
+    ``begin`` is the profiler annotation's.  ``instant`` records a
     zero-duration marker (used for live STR002 flags).
+
+    An enabled tracer also records every Python collection as a ``gc``
+    span on the ``host`` track (``gen``, ``collected``) through one
+    ``gc.callbacks`` entry.  The entry holds the tracer weakly and goes
+    away when the tracer is freed or closed.
     """
 
     def __init__(self, capacity: int = 65536, enabled: bool = True) -> None:
@@ -77,35 +114,56 @@ class Tracer:
         self.enabled = enabled
         self._buf: list[Span] = []
         self._n = 0  # total spans ever recorded (>= len(_buf) once wrapped)
+        self._gc_hook = _gc_hook(self) if enabled else None
 
     # -- recording -------------------------------------------------------
 
-    def t(self) -> int:
-        """Span-start timestamp; 0 when disabled (callers pass it back)."""
+    def begin(self, name: str) -> int:
+        """Span-start stamp; 0 when disabled (callers pass it to ``add``).
+        While a profiler trace records, also opens an annotation ``name``."""
         if not self.enabled:
             return 0
-        return time.perf_counter_ns()
+        tm = _TraceMe if _TraceMe is not None else _find_traceme()
+        if tm is None or not tm.is_enabled():
+            return time.perf_counter_ns()
+        ann = tm(name)
+        mark = _Mark(time.perf_counter_ns())
+        mark.ann = ann
+        return mark
 
     def add(self, track: str, name: str, t0_ns: int, **args: Any) -> None:
         if not self.enabled:
             return
-        span = Span(track, name, t0_ns, time.perf_counter_ns(), args)
+        t1_ns = time.perf_counter_ns()
+        if type(t0_ns) is _Mark:
+            t0_ns.ann.__exit__(None, None, None)
+            t0_ns = int(t0_ns)
+        self._put(Span(track, name, t0_ns, t1_ns, args))
+
+    def instant(self, track: str, name: str, **args: Any) -> None:
+        if not self.enabled:
+            return
+        now = self.begin(name)
+        if type(now) is _Mark:
+            now.ann.__exit__(None, None, None)
+            now = int(now)
+        self._put(Span(track, name, now, now, args))
+
+    def _put(self, span: Span) -> None:
+        # Allocates nothing the collector tracks, so no collection (and no
+        # ``gc`` span) can start in here: a span is placed whole, before or
+        # after the one a collection inside ``Span(...)`` records.
         if len(self._buf) < self.capacity:
             self._buf.append(span)
         else:
             self._buf[self._n % self.capacity] = span
         self._n += 1
 
-    def instant(self, track: str, name: str, **args: Any) -> None:
-        if not self.enabled:
-            return
-        now = time.perf_counter_ns()
-        span = Span(track, name, now, now, args)
-        if len(self._buf) < self.capacity:
-            self._buf.append(span)
-        else:
-            self._buf[self._n % self.capacity] = span
-        self._n += 1
+    def close(self) -> None:
+        """Stop recording collections; spans stay readable."""
+        hook, self._gc_hook = self._gc_hook, None
+        if hook is not None and hook in gc.callbacks:
+            gc.callbacks.remove(hook)
 
     # -- inspection ------------------------------------------------------
 
@@ -164,6 +222,36 @@ class Tracer:
         return doc
 
 
+def _gc_hook(tracer: Tracer):
+    """Register a ``gc.callbacks`` entry that records each collection as a
+    ``gc`` span on ``tracer``; it holds the tracer weakly and removes
+    itself when the tracer is freed."""
+    t0 = 0
+
+    def hook(phase: str, info: dict[str, int]) -> None:
+        nonlocal t0
+        tr = ref()
+        if tr is None:
+            return
+        if phase == "start":
+            t0 = tr.begin("gc")
+        elif t0:
+            tr.add("host", "gc", t0, gen=info["generation"],
+                   collected=info["collected"])
+            t0 = 0
+
+    # The list itself: the module's name for it is gone at interpreter exit.
+    callbacks = gc.callbacks
+
+    def unhook(_ref) -> None:
+        if hook in callbacks:
+            callbacks.remove(hook)
+
+    ref = weakref.ref(tracer, unhook)
+    callbacks.append(hook)
+    return hook
+
+
 def read_trace(path: str) -> list[Span]:
     """Parse a Chrome trace written by :meth:`Tracer.to_chrome` back to spans."""
     with open(path) as f:
@@ -185,35 +273,3 @@ def read_trace(path: str) -> list[Span]:
             args=dict(ev.get("args", {})),
         ))
     return sorted(spans, key=lambda s: (s.t0_ns, s.t1_ns))
-
-
-def span_tree(spans: Iterable[Span]) -> dict[str, list[dict[str, Any]]]:
-    """Nest spans by containment, per track.
-
-    Returns ``{track: [node, ...]}`` where each node is
-    ``{"span": Span, "children": [node, ...]}``.  A span B is a child of
-    A when A's interval contains B's and A started first (ties broken by
-    longer-first ordering, matching how the Chrome viewer nests them).
-    """
-    tree: dict[str, list[dict[str, Any]]] = {}
-    by_track: dict[str, list[Span]] = {}
-    for s in spans:
-        by_track.setdefault(s.track, []).append(s)
-    for track, ss in by_track.items():
-        ss = sorted(ss, key=lambda s: (s.t0_ns, -s.t1_ns))
-        roots: list[dict[str, Any]] = []
-        stack: list[dict[str, Any]] = []
-        for s in ss:
-            node = {"span": s, "children": []}
-            while stack and stack[-1]["span"].t1_ns < s.t1_ns:
-                stack.pop()
-            while stack and not (stack[-1]["span"].t0_ns <= s.t0_ns
-                                 and s.t1_ns <= stack[-1]["span"].t1_ns):
-                stack.pop()
-            if stack:
-                stack[-1]["children"].append(node)
-            else:
-                roots.append(node)
-            stack.append(node)
-        tree[track] = roots
-    return tree

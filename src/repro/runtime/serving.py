@@ -904,20 +904,26 @@ class StreamedBatchEngine:
         return slot_key(uid, step)
 
     @tick_path(allowed_fetches=1)
-    def _sample(self, logits_row: jax.Array, uid: int, step: int) -> int:
-        """Per-request sampling: greedy, or temperature via the slot key.
+    def _sample(self, logits: jax.Array, uid: int, step: int) -> int:
+        """Per-request sampling of the last position of ``logits``
+        (1, S, vocab): greedy, or temperature via the slot key.
 
         The pick is reduced on device, then fetched once via the declared
         ``host_fetch`` — ``int()`` straight on the device scalar was the
         hidden-sync shape STR001 exists to catch.
         """
+        dt0 = self.obs.begin("dispatch")
+        logits_row = logits[0, -1]
         if self.scfg.temperature > 0.0:
             pick = jax.random.categorical(
                 self._slot_key(uid, step),
                 logits_row / self.scfg.temperature)
         else:
             pick = jnp.argmax(logits_row, axis=-1)
+        self.obs.add("host", "dispatch", dt0, uid=uid)
+        ft0 = self.obs.begin("fetch_wait")
         val = host_fetch(pick)
+        self.obs.add("host", "fetch_wait", ft0, uid=uid)
         self.metrics.inc("transfer.d2h_bytes", int(val.nbytes))
         return int(val)
 
@@ -936,7 +942,12 @@ class StreamedBatchEngine:
         full prefill would (bitwise token parity with the unshared path).
         """
         t0 = time.perf_counter()
-        ot0 = self.obs.t()
+        ot0 = self.obs.begin("admit")
+        # Host phases of the admission (``host`` track, uid=): ``stage``
+        # (page reservation, token upload, per-chunk table and lookup,
+        # publish), ``dispatch``, ``fetch_wait`` of the first token, then
+        # ``emit`` up to the close of the ``admit`` span.
+        st0 = self.obs.begin("stage")
         # Queue wait: submit -> queue pop.  Direct _admit calls (tests)
         # carry no submit stamp; they waited nothing.
         queue_wait = max(0.0, t0 - req.t_submit) if req.t_submit else 0.0
@@ -989,7 +1000,7 @@ class StreamedBatchEngine:
                 shared_len = n
                 self.snapshot_hits += 1
                 self.snapshot_tokens_reused += n
-        ht0 = self.obs.t()
+        ht0 = self.obs.begin("h2d_stage")
         tokens = jnp.asarray(req.tokens[None, shared_len:], jnp.int32)
         self.obs.add("transfer", "h2d_stage", ht0, uid=req.uid,
                      h2d_bytes=int(len(req.tokens) - shared_len) * 4)
@@ -1008,19 +1019,25 @@ class StreamedBatchEngine:
             row = np.full((1, self.kv.max_pages), 0, np.int32)
             own = self.kv.slot_pages(slot.index)
             row[0, : len(own)] = own
+        self.obs.add("host", "stage", st0, uid=req.uid)
+        if use_fused:
             s_total = tokens.shape[1]
             # Same chunk grid as iter_prefill_chunks (anchored at absolute
             # position 0), so the fused path dispatches the exact chunk
             # tasks the legacy path would — fp32 parity is bitwise.
             chunk = min(self.scfg.prefill_chunk, shared_len + s_total)
             for lo in range(0, s_total, chunk):
-                ct0 = self.obs.t()
+                ct0 = self.obs.begin("prefill_chunk")
+                st0 = self.obs.begin("stage")
                 piece = tokens[:, lo: lo + chunk]
                 n_ctx = self.kv.pages_for(pos + piece.shape[1])
                 fn = self.single._fused_chunk_fn(piece.shape[1], pos)
+                table = jnp.asarray(row[:, :n_ctx])
+                self.obs.add("host", "stage", st0, uid=req.uid)
+                dt0 = self.obs.begin("dispatch")
                 logits, self.kv.pools = fn(
-                    self.params, self.kv.pools,
-                    jnp.asarray(row[:, :n_ctx]), piece)
+                    self.params, self.kv.pools, table, piece)
+                self.obs.add("host", "dispatch", dt0, uid=req.uid)
                 pos += piece.shape[1]
                 n_chunks += 1
                 # Chunk is dispatched (async); decode the active slots while
@@ -1034,9 +1051,16 @@ class StreamedBatchEngine:
                 self.obs.add("prefill", "prefill_chunk", ct0,
                              uid=req.uid, pos=pos, fused=True)
         else:
-            ct0 = self.obs.t()
+            # As many chunks as the generator's grid (the fused loop's)
+            # holds, so that no span opens after the last one.
+            s_total = tokens.shape[1]
+            left = -(-s_total // min(self.scfg.prefill_chunk,
+                                     shared_len + s_total))
+            ct0 = self.obs.begin("prefill_chunk")
+            dt0 = self.obs.begin("dispatch")
             for logits, caches, pos in self.servable.iter_prefill_chunks(
                     req, tokens, caches=caches0, pos0=shared_len):
+                self.obs.add("host", "dispatch", dt0, uid=req.uid)
                 self.servable.maybe_snapshot(req.tokens, caches, pos)
                 n_chunks += 1
                 # Chunk is dispatched (async); decode the active slots while
@@ -1048,13 +1072,17 @@ class StreamedBatchEngine:
                 # In-flight window span (see the fused loop above).
                 self.obs.add("prefill", "prefill_chunk", ct0,
                              uid=req.uid, pos=pos, fused=False)
-                ct0 = self.obs.t()
+                left -= 1
+                if left:  # the next chunk's span opens here
+                    ct0 = self.obs.begin("prefill_chunk")
+                    dt0 = self.obs.begin("dispatch")
+        st0 = self.obs.begin("stage")
         if self.paged:
             if not use_fused:  # fused chunks already wrote the pool blocks
-                st0 = self.obs.t()
+                pt0 = self.obs.begin("page_scatter")
                 self.kv.scatter(
                     slot.index, caches, pos, start_page=shared_pages)
-                self.obs.add("transfer", "page_scatter", st0, uid=req.uid,
+                self.obs.add("transfer", "page_scatter", pt0, uid=req.uid,
                              pages=int(self.kv.pages_for(pos)
                                        - shared_pages))
             self.kv.publish(slot.index)
@@ -1064,11 +1092,13 @@ class StreamedBatchEngine:
                     min_pages=self.scfg.prefix_min_pages,
                     align_tokens=self.scfg.prefill_chunk)
         else:
-            st0 = self.obs.t()
+            pt0 = self.obs.begin("slot_scatter")
             self.caches = self._scatter_jit(
                 self.caches, caches, jnp.int32(slot.index))
-            self.obs.add("transfer", "slot_scatter", st0, uid=req.uid)
-        first = self._sample(logits[0, -1], req.uid, 0)
+            self.obs.add("transfer", "slot_scatter", pt0, uid=req.uid)
+        self.obs.add("host", "stage", st0, uid=req.uid)
+        first = self._sample(logits, req.uid, 0)
+        et0 = self.obs.begin("emit")
         slot.uid = req.uid
         slot.cur = pos
         slot.pending = first
@@ -1087,6 +1117,7 @@ class StreamedBatchEngine:
         slot.evictions = 0
         self.metrics.observe("latency.ttft_s", dt)
         self.metrics.inc("serving.tokens_emitted", 1)  # the first token
+        self.obs.add("host", "emit", et0, uid=req.uid)
         self.obs.add("prefill", "admit", ot0, uid=req.uid, chunks=n_chunks,
                      shared_len=shared_len, prompt_len=len(req.tokens),
                      slot=slot.index, queue_wait_s=queue_wait,
@@ -1153,15 +1184,19 @@ class StreamedBatchEngine:
         still short.  Re-checks after reclaiming because reclaim may have
         dropped the matched entry itself.  The surviving match is stashed
         for ``_admit`` so the admission doesn't repeat the lookup."""
+        st0 = self.obs.begin("schedule")
         full = self.kv.pages_for(len(req.tokens) + 1)
+        fits = False
         for _ in range(3):  # match -> reclaim -> match-dropped converges
             n, blocks, probed = self._lookup_prefix(req)
             if full - n <= self.kv.free_pages:
                 self._gate_match = (req.uid, n, blocks, probed)
-                return True
+                fits = True
+                break
             if not self.kv.reclaim_for(full - n):
-                return False
-        return False
+                break
+        self.obs.add("host", "schedule", st0)
+        return fits
 
     @tick_path(allowed_fetches=0)
     def _decode_tick(self) -> None:
@@ -1179,7 +1214,9 @@ class StreamedBatchEngine:
         other slot is left to victimize — e.g. the rest of the pool is
         reserved by an admission's in-flight prefill — the faulting
         slot preempts itself and waits for pages.  (Shared by the plain
-        and the speculative tick: one fault/preempt policy.)"""
+        and the speculative tick: one fault/preempt policy.)  Its
+        ``page_fault`` span carries the number of the tick it precedes."""
+        pt0 = self.obs.begin("page_fault")
         for s in sorted(self.active_slots, key=lambda s: s.seq):
             if s.uid is None:
                 continue  # preempted by an earlier iteration
@@ -1188,13 +1225,15 @@ class StreamedBatchEngine:
                     self._preempted.append(self.evict(s.uid))
                     self.preemptions += 1
                     break
+        self.obs.add("host", "page_fault", pt0, tick=self._tick_index + 1)
 
     def _account_tick(self, name: str, ot0: int, dt: float, *,
-                      n_slots: int, new_tokens: int, d2h_bytes: int,
-                      h2d_bytes: int, budget: Any,
+                      emit_t0: int, n_slots: int, new_tokens: int,
+                      d2h_bytes: int, h2d_bytes: int, budget: Any,
                       attrib: dict[str, Any] | None = None) -> None:
         """Per-tick bookkeeping shared by the plain and speculative ticks:
         metrics (token/byte counters, per-tick transfer histograms), the
+        tick's ``emit`` phase (opened at ``emit_t0``) and then its
         decode-track span (``attrib`` carries the per-slot uid/token
         attribution lists when tracing is on), and runtime transfer
         accounting — fetched bytes checked against the step builder's
@@ -1210,6 +1249,7 @@ class StreamedBatchEngine:
         m.inc("transfer.h2d_bytes", h2d_bytes)
         m.observe("transfer.d2h_bytes_per_tick", d2h_bytes)
         self._tick_index += 1
+        self.obs.add("host", "emit", emit_t0, tick=self._tick_index)
         self.obs.add("decode", name, ot0, tick=self._tick_index,
                      slots=n_slots, tokens=new_tokens,
                      d2h_bytes=d2h_bytes, h2d_bytes=h2d_bytes,
@@ -1297,8 +1337,12 @@ class StreamedBatchEngine:
         act = self.active_slots
         if not act:
             return
-        ot0 = self.obs.t()
+        # Host phases (``host`` track, the tick's number): ``page_fault``
+        # above, then ``stage``, ``dispatch``, ``fetch_wait`` and ``emit``.
+        tick = self._tick_index + 1
+        ot0 = self.obs.begin("decode_tick")
         t0 = time.perf_counter()
+        st0 = self.obs.begin("stage")
         b = self.scfg.max_batch
         toks = np.zeros((b, 1), np.int32)
         cur = np.zeros((b,), np.int32)
@@ -1319,17 +1363,21 @@ class StreamedBatchEngine:
                 uids[s.index] = s.uid
                 steps[s.index] = len(s.emitted)
             args += [jnp.asarray(uids), jnp.asarray(steps)]
+        self.obs.add("host", "stage", st0, tick=tick)
+        dt0 = self.obs.begin("dispatch")
         nxt, new_caches = self._decode_jit(*args)
         if self.paged:
             self.kv.pools = new_caches
         else:
             self.caches = new_caches
         self.decode_steps += 1
+        self.obs.add("host", "dispatch", dt0, tick=tick)
+        ft0 = self.obs.begin("fetch_wait")
         picks = host_fetch(nxt)  # (B,) int32 — the tick's only D2H
         t1 = time.perf_counter()
-        attrib = (dict(uids=[s.uid for s in act],
-                       slot_ids=[s.index for s in act],
-                       toks=[1] * len(act))
+        self.obs.add("host", "fetch_wait", ft0, tick=tick)
+        et0 = self.obs.begin("emit")
+        attrib = (dict(uids=[s.uid for s in act], toks=[1] * len(act))
                   if self.obs.enabled else None)
         for s in act:
             s.cur += 1
@@ -1344,7 +1392,7 @@ class StreamedBatchEngine:
             self.metrics.observe("latency.itl_s", gap)
             self._reap(s)
         self._account_tick(
-            "decode_tick", ot0, t1 - t0,
+            "decode_tick", ot0, t1 - t0, emit_t0=et0,
             n_slots=len(act), new_tokens=len(act),
             d2h_bytes=int(picks.nbytes), h2d_bytes=h2d_bytes,
             budget=self._decode_budget, attrib=attrib)
@@ -1381,13 +1429,14 @@ class StreamedBatchEngine:
         act = self.active_slots
         if not act:
             return
-        ot0 = self.obs.t()
+        tick = self._tick_index + 1
+        ot0 = self.obs.begin("spec_tick")
         t0 = time.perf_counter()
         b = self.scfg.max_batch
         toks = np.zeros((b, k + 1), np.int32)
         cur = np.zeros((b,), np.int32)
         d_len = np.zeros((b,), np.int32)
-        dt0 = self.obs.t()
+        dt0 = self.obs.begin("spec_draft")
         for s in act:
             toks[s.index, 0] = s.pending
             cur[s.index] = s.cur
@@ -1414,7 +1463,6 @@ class StreamedBatchEngine:
         self.obs.add("decode", "spec_draft", dt0,
                      proposed=int(d_len.sum()),
                      **(dict(uids=[s.uid for s in act],
-                             slot_ids=[s.index for s in act],
                              drafted=[int(d_len[s.index]) for s in act])
                         if self.obs.enabled else {}))
         if not int(d_len.sum()):
@@ -1423,6 +1471,7 @@ class StreamedBatchEngine:
             # ~(k+1)x a plain tick's compute with zero possible acceptance
             # — dispatch the already-compiled single-token step instead.
             return self._plain_tick()
+        st0 = self.obs.begin("stage")
         args = [self.params, jnp.asarray(toks)]
         if self.paged:
             args += [self.kv.pools, self.kv.device_page_table()]
@@ -1436,6 +1485,8 @@ class StreamedBatchEngine:
                 uids[s.index] = s.uid
                 steps[s.index] = len(s.emitted)
             args += [jnp.asarray(uids), jnp.asarray(steps)]
+        self.obs.add("host", "stage", st0, tick=tick)
+        dt0 = self.obs.begin("dispatch")
         emit, n_accept, new_caches = self._spec_jit(*args)
         if self.paged:
             self.kv.pools = new_caches
@@ -1443,13 +1494,16 @@ class StreamedBatchEngine:
             self.caches = new_caches
         self.decode_steps += 1
         self.spec_ticks += 1
+        self.obs.add("host", "dispatch", dt0, tick=tick)
+        ft0 = self.obs.begin("fetch_wait")
         emit = host_fetch(emit)  # (B, k+1) + (B,): the tick's only D2H
         n_accept = host_fetch(n_accept)
         new_tokens = 0
         t1 = time.perf_counter()
-        rt0 = self.obs.t()
-        attrib = (dict(uids=[s.uid for s in act],
-                       slot_ids=[s.index for s in act], toks=[])
+        self.obs.add("host", "fetch_wait", ft0, tick=tick)
+        et0 = self.obs.begin("emit")
+        rt0 = self.obs.begin("spec_rollback") if self.paged else 0
+        attrib = (dict(uids=[s.uid for s in act], toks=[])
                   if self.obs.enabled else None)
         for s in act:
             n = int(n_accept[s.index])
@@ -1482,7 +1536,7 @@ class StreamedBatchEngine:
                          accepted=new_tokens - len(act),
                          **({"uids": attrib["uids"]} if attrib else {}))
         self._account_tick(
-            "spec_tick", ot0, t1 - t0,
+            "spec_tick", ot0, t1 - t0, emit_t0=et0,
             n_slots=len(act), new_tokens=new_tokens,
             d2h_bytes=int(emit.nbytes) + int(n_accept.nbytes),
             h2d_bytes=(int(toks.nbytes) + int(cur.nbytes)
@@ -1513,6 +1567,7 @@ class StreamedBatchEngine:
             # count both before giving up.  The match -> reclaim ->
             # match-dropped loop converges like the admission gate's.
             while self._preempted and any(s.free for s in self.slots):
+                st0 = self.obs.begin("schedule")
                 ev0 = self._preempted[0]
                 full = self.kv.pages_for(ev0.cur + 1)
                 fits = False
@@ -1523,6 +1578,7 @@ class StreamedBatchEngine:
                         break
                     if not self.kv.reclaim_for(full - shared):
                         break
+                self.obs.add("host", "schedule", st0)
                 if not fits:
                     break
                 self.readmit(self._preempted.popleft())
@@ -1559,7 +1615,7 @@ class StreamedBatchEngine:
         slot = next((s for s in self.slots if s.uid == uid), None)
         if slot is None:
             raise KeyError(f"uid {uid} not active")
-        et0 = self.obs.t()
+        et0 = self.obs.begin("evict")
         if self.paged:
             caches = self.kv.gather(slot.index, slot.cur)
             n_pages = self.kv.pages_for(slot.cur)
@@ -1610,7 +1666,7 @@ class StreamedBatchEngine:
         slot = next((s for s in self.slots if s.free), None)
         if slot is None:
             raise RuntimeError("no free slot to readmit into")
-        rt0 = self.obs.t()
+        rt0 = self.obs.begin("readmit")
         shared_pages = 0
         if self.paged:
             shared_pages, blocks = self._readmit_prefix(ev)

@@ -259,11 +259,11 @@ class TestCLI:
         makes the CLI exit 1 under --fail-on high."""
         tr = Tracer()
         for uid in range(4):
-            t0 = tr.t()
+            t0 = tr.begin("admit")
             tr.add("prefill", "admit", t0, uid=uid, chunks=1,
                    shared_len=0, prompt_len=8, slot=0, queue_wait_s=0.0,
                    max_new=99)
-            tr.add("transfer", "evict", tr.t(), uid=uid, pages=4, cur=9,
+            tr.add("transfer", "evict", tr.begin("evict"), uid=uid, pages=4, cur=9,
                    slot=0)
         path = tmp_path / "bad.json"
         tr.to_chrome(str(path))
