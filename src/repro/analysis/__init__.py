@@ -45,7 +45,7 @@ RULES = {
     "KRN001": "BlockSpec/grid inconsistent with the wrapper's declared "
               "operand shapes (rank, arity, divisibility, TPU tiling)",
     "KRN002": "scalar-prefetch operand never used as an index by any "
-              "BlockSpec index_map",
+              "BlockSpec index_map, nor by the body's declared DMAs",
     "KRN003": "quant kernel dtype contract broken against quant.py "
               "scale/code layouts",
     "KRN004": "ops.* wrapper signature diverges from its ref.* oracle",

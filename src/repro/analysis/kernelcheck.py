@@ -13,10 +13,16 @@ Rules:
   break the TPU tiling rule (divisible by 8 and 128, or equal to the
   operand's dims — Mosaic refuses anything else at compile time), index
   maps whose arity doesn't match ``len(grid) + num_scalar_prefetch`` or
-  that return the wrong number of coordinates.
+  that return the wrong number of coordinates. An operand left in HBM
+  (``memory_space=pl.ANY``, no block) must be declared in the layout's
+  ``hbm_operands`` with the rank the body's copies index, which must be
+  the operand's; a blockless spec that is not declared is a finding.
 * **KRN002** — a scalar-prefetch operand no index map ever reads: the
   kernel DMAs the scalars every step and then ignores them (a dead
-  prefetch is almost always a page-table wiring bug).
+  prefetch is almost always a page-table wiring bug). A prefetch that the
+  body's own DMAs index instead is declared in ``KERNEL_META``'s
+  ``dma_index_operands``; that declaration holds only while the layout
+  has an HBM operand for such a DMA to read.
 * **KRN003** — dtype contract between the quantized kernels and the
   ``kernels.quant`` pool layout: pools enter as the storage dtype, scales
   as f32 with the per-(page, kv-head) shape, output comes back in the
@@ -34,6 +40,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from repro.analysis import Finding
 
@@ -136,20 +143,45 @@ def check_layout(name: str, meta: dict) -> list[Finding]:
             f"grid rank {len(grid)} != documented grid_dims "
             f"{meta['grid_dims']}", "kernel"))
     recorders = [_Recorder() for _ in range(n_prefetch)]
+    hbm = sp.get("hbm_operands", {})
     for i, (spec, op) in enumerate(zip(in_specs, operands)):
-        _check_spec(name, f"in[{i}]", spec, op, grid, n_prefetch,
-                    recorders, findings)
+        if i in hbm or spec.block_shape is None:
+            _check_hbm(name, f"in[{i}]", spec, op, hbm.get(i), findings)
+        else:
+            _check_spec(name, f"in[{i}]", spec, op, grid, n_prefetch,
+                        recorders, findings)
     _check_spec(name, "out", sp["out_specs"], sp["out_shape"], grid,
                 n_prefetch, recorders, findings)
     index_ops = sp.get("prefetch_index_operands",
                        tuple(range(n_prefetch)))
+    dma_ops = meta.get("dma_index_operands", ()) if hbm else ()
     for i, rec in enumerate(recorders):
-        if i in index_ops and not rec.used:
+        if i in index_ops and not rec.used and i not in dma_ops:
             findings.append(Finding(
                 "KRN002", f"{name}:prefetch[{i}]",
                 "scalar-prefetch operand is declared index-bearing but no "
                 "index_map ever reads it (dead prefetch)", "kernel"))
     return findings
+
+
+def _check_hbm(name: str, what: str, spec, op_shape, rank,
+               findings: list[Finding]) -> None:
+    """KRN001 for an operand the kernel leaves in HBM and copies itself."""
+    target = f"{name}:{what}"
+    if rank is None:
+        findings.append(Finding(
+            "KRN001", target,
+            "blockless spec not declared in hbm_operands", "kernel"))
+    elif spec.block_shape is not None or spec.memory_space != pl.ANY:
+        findings.append(Finding(
+            "KRN001", target,
+            f"declared HBM-resident but its spec is blocked "
+            f"({spec.block_shape}) in {spec.memory_space}", "kernel"))
+    elif rank != len(op_shape):
+        findings.append(Finding(
+            "KRN001", target,
+            f"copies index rank {rank}, operand {tuple(op_shape)} has rank "
+            f"{len(op_shape)}", "kernel"))
 
 
 def check_quant_contract() -> list[Finding]:

@@ -84,8 +84,8 @@ def paged_attention(
     interpret=None,
 ):
     """Decode attention directly from the paged KV pool: the page table is
-    scalar-prefetched so the gather happens inside the kernel's block-fetch
-    DMAs instead of materializing a contiguous copy in HBM."""
+    scalar-prefetched and the kernel copies each row's live pages into VMEM
+    itself, instead of materializing a contiguous copy in HBM."""
     interpret = _default_interpret() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     return _pa.paged_attention_kernel(
@@ -134,8 +134,8 @@ def paged_attention_quant(
     interpret=None,
 ):
     """Quantized-pool decode attention with dequantization fused into the
-    block compute: the DMA moves narrow codes, the scale rides the same
-    scalar-prefetched page index, and full-precision K/V never exists in
+    block compute: the DMA moves narrow codes, a page's scales are taken by
+    the same physical id, and full-precision K/V never exists in
     pool-resident form."""
     interpret = _default_interpret() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])

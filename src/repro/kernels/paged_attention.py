@@ -2,28 +2,47 @@
 
 The TPU twin of ``repro.models.attention.paged_decode_attention``: instead
 of gathering a slot's pages into a contiguous (B, S, Hkv, hd) view in HBM,
-the page table is **scalar-prefetched** and each grid step's K/V BlockSpec
-indexes the physical pool block directly — the gather happens inside the
-block-fetch DMA, which Mosaic pipelines against the previous page's MXU
-compute (the paper's stream overlap, with pages as the Independent transfer
-tasks).
+the kernel copies each row's live pages straight from the pool into VMEM,
+through the scalar-prefetched page table.
 
-Grid: (batch, n_pages) — the page stream is the innermost (sequential)
-dimension.  Each step fetches one whole physical page with all its KV heads
-(block ``(1, block_size, Hkv, hd)``: the last two block dims equal the
-pool's, which is what Mosaic's (8, 128) tiling rule accepts), so a page is
-read once per (row, page) rather than once per KV head.  A static loop over
-the KV heads runs inside the body; each head keeps its own online-softmax
-state (m, l, acc) in VMEM scratch across the page stream, exactly like
-``flash_attention``'s KV stream.  Pages fully beyond a row's ``cur_len`` (or
-outside its sliding window) skip compute via ``pl.when``; in-page masking is
-positional (iota vs ``cur_len``), so trash-page garbage never contributes.
+Grid: (batch, n_blocks), with n_blocks = ceil(n_pages / P), walked in row
+order. A compute block is P logical pages of one row, P * block_size keys.
+P follows from the shapes (:func:`pages_per_block`: ``KEYS_PER_BLOCK``
+keys, clamped to [1, n_pages]), so the last block may be partial.
+
+DMA: the K and V pools stay in HBM (``memory_space=pl.ANY``); each block's
+pages are copied, one async copy per page by the physical id in the table,
+into one half of a double buffer ``(2, P, block_size, Hkv, hd)``. Only live
+pages are copied: a page is live for a row when it holds a position at or
+below the row's youngest query (``cur_len + q_len - 1``), inside the oldest
+query's window when ``window > 0``, and lies inside the table. A row's live
+pages are one run, so a block's are a range of its slots, and a row's live
+blocks are consecutive. Each live step starts the copies of the next live
+block in row order into the other half (the same row's next block, or after
+the row's last live block the next row's first), then waits for its own and
+computes. A block with no live page issues no copy and no compute, so a
+dead tail costs only empty grid steps. The V slots of a live block that
+were not copied are zeroed, so stale VMEM never meets a zero softmax weight.
+A quantized pool's (num_blocks, Hkv) f32 scales sit whole in VMEM; a page's
+row of scales is copied beside the page, by the same physical id.
+
+Compute: all KV heads at once. The queries of every head, ``Hkv * q_len *
+g`` rows, are scored against the whole block viewed as ``(P * block_size *
+Hkv, hd)`` (key-major, heads minor) in one product, and a score counts only
+where the key's head is the row's head. One PV product over the same view
+follows. A head's keys are thus read with no strided access, at the price
+of Hkv times the MXU work, which is cheap beside the copies. Each row keeps
+its online-softmax state (m, l, acc) in f32 VMEM scratch across the block
+stream. Masking is positional (iota vs ``cur_len``, and the table's end
+where the last block runs past it), so pages that were not copied, the
+trash page and positions past a row's end never contribute. A
+quantized pool's codes enter the products unscaled; each key's scale
+multiplies its score, and each value's the weight it is summed with.
 
 ``q_len > 1`` (speculative multi-token decode) folds the query block into
-the row dimension: the kernel scores ``q_len * g`` query rows per KV head,
-with row ``r``'s query sitting at absolute position ``cur_len + r // g`` —
-the causal-within-the-block mask of the verify step.  A page is skipped
-only when *every* query in the block masks it (the youngest query bounds
+the rows: row ``r`` of a head is query ``r // g``, at absolute position
+``cur_len + r // g``, the causal-within-the-block mask of the verify step. A
+page is dead only when *every* query masks it (the youngest query bounds
 the causal cut, the oldest bounds the window cut).
 """
 
@@ -38,20 +57,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: Keys of one compute block; P = this // block_size pages.
+KEYS_PER_BLOCK = 512
+
+
+def pages_per_block(block_size: int, n_pages: int) -> int:
+    """P, the pages of one compute block: ``KEYS_PER_BLOCK`` keys' worth,
+    at least one page and at most the whole table."""
+    return max(1, min(KEYS_PER_BLOCK // block_size, n_pages))
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
 
 def _paged_kernel(
     pt_ref,  # SMEM (B, n_pages) int32: scalar-prefetched page table
     cl_ref,  # SMEM (B,) int32: per-row current position
-    q_ref,  # (1, Hkv, q_len * g, hd)
-    k_ref,  # (1, bs, Hkv, hd): one physical page, all kv heads
-    v_ref,  # (1, bs, Hkv, hd)
-    *rest,  # quantized: (ks_ref, vs_ref, o_ref, m, l, acc) — the per-page
-    # per-head f32 scales, (1, 1, Hkv), ride the same scalar-prefetched
-    # indexing as the page itself, so dequantization is fused into the
-    # block compute (the pool's narrow codes are what the DMA moves); else
-    # (o_ref, m, l, acc)
+    q_ref,  # (1, Hkv * q_len * g, hd): head-major rows
+    k_hbm,  # (num_blocks, bs, Hkv, hd) pool, left in HBM
+    v_hbm,
+    *rest,  # quantized: (ks_ref, vs_ref, o_ref, m, l, acc, kbuf, vbuf,
+    # ksbuf, vsbuf, par, sem), the scale pools (num_blocks, Hkv) whole in
+    # VMEM and their per-page rows (2, P, 1, Hkv); else (o_ref, m, l, acc,
+    # kbuf, vbuf, par, sem)
     n_pages: int,
-    block_size: int,
+    pages: int,
     q_len: int,
     group: int,
     window: int,
@@ -60,13 +91,49 @@ def _paged_kernel(
     quantized: bool = False,
 ):
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        (ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, kbuf, vbuf, ksbuf,
+         vsbuf, par_ref, sem) = rest
     else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, m_ref, l_ref, acc_ref, kbuf, vbuf, par_ref, sem = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
-    hkv = k_ref.shape[2]
+    n_rows = pl.num_programs(0)
+    _, _, bs, hkv, hd = kbuf.shape
+
+    def live_pages(row):
+        """The row's live pages [first, end): from the oldest query's window
+        (all of them without one) through the youngest query's position,
+        cut at the table's end (a draft block's padding tail runs past it)."""
+        cur = cl_ref[row]
+        first = jnp.maximum(cur - window + 1, 0) // bs if window > 0 else 0
+        end = jnp.minimum((cur + q_len - 1) // bs + 1, n_pages)
+        return cur, first, end
+
+    def live(blk, first, end):
+        """Slots [lo, hi) of block ``blk`` that hold live pages."""
+        base = blk * pages
+        return (jnp.clip(first - base, 0, pages),
+                jnp.clip(end - base, 0, pages))
+
+    def copies(row, blk, slot, i):
+        pid = pt_ref[row, blk * pages + i]
+        return pid, [pltpu.make_async_copy(src.at[pid], dst.at[slot, i],
+                                           sem.at[slot])
+                     for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))]
+
+    def fetch(row, blk, first, end, slot):
+        def start(i, carry):
+            pid, cps = copies(row, blk, slot, i)
+            for cp in cps:
+                cp.start()
+            if quantized:
+                ksbuf[slot, i] = ks_ref[pl.ds(pid, 1), :]
+                vsbuf[slot, i] = vs_ref[pl.ds(pid, 1), :]
+            return carry
+        jax.lax.fori_loop(*live(blk, first, end), start, 0)
+
+    cur, first, end = live_pages(b)
+    last = (end - 1) // pages  # the row's last live block
 
     @pl.when(j == 0)
     def _init():
@@ -74,134 +141,172 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cur = cl_ref[b]
-    # Page-level pruning: skip pages entirely past the *youngest* query
-    # (cur + q_len - 1; the unallocated tail's table entries point at the
-    # trash page) or behind the *oldest* query's window.
-    live = j * block_size <= cur + (q_len - 1)
-    if window > 0:
-        live = live & (cur - (j * block_size + block_size - 1) < window)
+    # Block j of this row sits in buffer half (j + par) % 2; par is chosen
+    # as each row's first live block is fetched, so that it lands in the
+    # half the previous row's last live block does not use.
+    @pl.when((b == 0) & (j == first // pages))
+    def _first():
+        par_ref[0] = 0
+        fetch(b, j, first, end, j % 2)
 
-    @pl.when(live)
-    def _compute():
-        rows = q_ref.shape[2]
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1)
-        # Row r is query r // group at absolute position cur + r // group:
-        # causal within the draft block, per query.
-        qpos = cur + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 0) // group
-        ok = pos <= qpos
+    lo, hi = live(j, first, end)
+
+    @pl.when(lo < hi)
+    def _step():
+        slot = (j + par_ref[0]) % 2
+
+        @pl.when(j < last)
+        def _next_block():
+            fetch(b, j + 1, first, end, 1 - slot)
+
+        @pl.when((j == last) & (b + 1 < n_rows))
+        def _next_row():
+            _, first1, end1 = live_pages(b + 1)
+            j1 = first1 // pages
+            fetch(b + 1, j1, first1, end1, 1 - slot)
+            par_ref[0] = (1 - slot + j1) % 2
+
+        def wait(i, carry):
+            for cp in copies(b, j, slot, i)[1]:
+                cp.wait()
+            return carry
+        jax.lax.fori_loop(lo, hi, wait, 0)
+
+        def zero(i, carry):
+            vbuf[slot, i] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+            if quantized:
+                vsbuf[slot, i] = jnp.zeros(vsbuf.shape[2:], vsbuf.dtype)
+            return carry
+        jax.lax.fori_loop(0, lo, zero, 0)
+        jax.lax.fori_loop(hi, pages, zero, 0)
+
+        rows = q_ref.shape[1]  # Hkv * q_len * g
+        cols = pages * bs * hkv  # key-major, heads minor
+        col = _iota((rows, cols), 1)
+        row = _iota((rows, cols), 0)
+        per_head = rows // hkv
+        pos = j * pages * bs + col // hkv
+        # Row r is query (r % per_head) // group of head r // per_head, at
+        # absolute position cur + that query: causal within a draft block.
+        qpos = cur + (row % per_head) // group
+        ok = (col % hkv == row // per_head) & (pos <= qpos)
         if window > 0:
             ok = ok & (qpos - pos < window)
+        if n_pages % pages:  # the last block runs past the table
+            ok = ok & (pos < n_pages * bs)
+        q = q_ref[0]  # (rows, hd)
+        k = kbuf[slot].reshape(cols, hd)
+        v = vbuf[slot].reshape(cols, hd)
         if quantized:
-            ks = ks_ref[0]  # (1, Hkv)
-            vs = vs_ref[0]
-        for h in range(hkv):  # static: one GQA group per kv head
-            q = q_ref[0, h]  # (rows, hd)
-            k = k_ref[0, :, h, :]  # (bs, hd)
-            v = v_ref[0, :, h, :]
-            if quantized:
-                k = k.astype(jnp.float32) * ks[:, h:h + 1]
-                v = v.astype(jnp.float32) * vs[:, h:h + 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if softcap > 0.0:
-                s = softcap * jnp.tanh(s / softcap)
-            s = jnp.where(ok, s, NEG_INF)
+            k = k.astype(q.dtype)  # int8 and e4m3 codes are exact in bf16
+            v = v.astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if quantized:
+            s = s * _col_scales(ksbuf[slot], bs)
+        if softcap > 0.0:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(ok, s, NEG_INF)
 
-            m_old = m_ref[h]  # (rows, 1)
-            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_old - m_new)
-            l_ref[h] = alpha * l_ref[h] + p.sum(axis=1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc_ref[h] = alpha * acc_ref[h] + pv
-            m_ref[h] = m_new
+        m_old = m_ref[...]  # (rows, 1)
+        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_old - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        if quantized:
+            p = p * _col_scales(vsbuf[slot], bs)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[...] = alpha * acc_ref[...] + pv
+        m_ref[...] = m_new
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
-                n_pages: int, *, quantized: bool) -> dict:
-    """Grid/BlockSpec layout shared by the kernel call *and* the analyzer's
-    kernel lint (``analysis.kernelcheck``).
+def _col_scales(sc: jax.Array, bs: int) -> jax.Array:
+    """(P, 1, Hkv) per-page scales -> (1, P * bs * Hkv): the scale of each
+    column of the block's key-major, heads-minor view."""
+    pages, _, hkv = sc.shape
+    sc = sc.reshape(pages, hkv)
+    shape = (pages, pages * bs * hkv)
+    col = _iota(shape, 1)
+    by_head = jnp.zeros(shape, jnp.float32)
+    for h in range(hkv):
+        by_head = jnp.where(col % hkv == h, sc[:, h:h + 1], by_head)
+    own_page = col // (bs * hkv) == _iota(shape, 0)
+    return jnp.where(own_page, by_head, 0.0).sum(axis=0, keepdims=True)
 
-    The page table and ``cur_len`` are the two scalar-prefetch operands —
-    every K/V (and scale) index_map must consume the prefetched table as an
-    index (``pt[bb, jj]``), which is exactly what the lint's KRN002 check
-    verifies; ``cur_len`` is body-consumed (position masking), so it is not
-    listed in ``prefetch_index_operands``.  ``operands``/``out_shape`` are
-    the wrapper-declared shapes each BlockSpec tiles (same order as
+
+def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
+                n_pages: int, *, quantized: bool,
+                pool_dtype=jnp.bfloat16) -> dict:
+    """Grid/BlockSpec layout shared by the kernel call *and* the analyzer's
+    kernel lint (``analysis.kernelcheck``). ``rows`` is the query rows per
+    KV head (``q_len * g``).
+
+    The page table and ``cur_len`` are the two scalar-prefetch operands.
+    The queries and the output are blocked per batch row. The pools are
+    HBM-resident (``pl.ANY``; ``hbm_operands`` names them and the rank the
+    body's copies index), so no index_map reads the page table: the body's
+    DMAs do, which ``KERNEL_META``'s ``dma_index_operands`` declares.
+    ``cur_len`` is body-consumed (position masking). ``operands`` /
+    ``out_shape`` are the wrapper-declared shapes (same order as
     ``in_specs``, prefetch excluded).
     """
-    in_specs = [
-        pl.BlockSpec((1, hkv, rows, hd),
-                     lambda bb, jj, pt, cl: (bb, 0, 0, 0)),
-        pl.BlockSpec((1, bs, hkv, hd),
-                     lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0, 0)),
-        pl.BlockSpec((1, bs, hkv, hd),
-                     lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0, 0)),
+    pages = pages_per_block(bs, n_pages)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    qo = pl.BlockSpec((1, hkv * rows, hd), lambda bb, jj, pt, cl: (bb, 0, 0))
+    in_specs = [qo, hbm, hbm]
+    operands = [(b, hkv * rows, hd), (nb, bs, hkv, hd), (nb, bs, hkv, hd)]
+    scratch = [
+        pltpu.VMEM((hkv * rows, 1), jnp.float32),
+        pltpu.VMEM((hkv * rows, 1), jnp.float32),
+        pltpu.VMEM((hkv * rows, hd), jnp.float32),
+        pltpu.VMEM((2, pages, bs, hkv, hd), pool_dtype),
+        pltpu.VMEM((2, pages, bs, hkv, hd), pool_dtype),
     ]
-    operands = [(b, hkv, rows, hd), (nb, bs, hkv, hd), (nb, bs, hkv, hd)]
     if quantized:
-        # The scale rides the page's scalar-prefetched index: one (1, 1,
-        # Hkv) block of the (num_blocks, 1, Hkv) scale view per grid step.
-        in_specs += [
-            pl.BlockSpec((1, 1, hkv),
-                         lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0)),
-            pl.BlockSpec((1, 1, hkv),
-                         lambda bb, jj, pt, cl: (pt[bb, jj], 0, 0)),
-        ]
-        operands += [(nb, 1, hkv), (nb, 1, hkv)]
+        # The (num_blocks, Hkv) scale pools are small (4 B a page and
+        # head): one whole block each, fetched once per call.
+        in_specs += [pl.BlockSpec((nb, hkv), lambda bb, jj, pt, cl: (0, 0))
+                     ] * 2
+        operands += [(nb, hkv), (nb, hkv)]
+        scratch += [pltpu.VMEM((2, pages, 1, hkv), jnp.float32)] * 2
     return dict(
-        grid=(b, n_pages),
+        grid=(b, pl.cdiv(n_pages, pages)),
+        pages=pages,
         num_scalar_prefetch=2,
         prefetch_index_operands=(0,),  # page table; cur_len is body-read
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, hkv, rows, hd), lambda bb, jj, pt, cl: (bb, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((hkv, rows, hd), jnp.float32),
-        ],
+        hbm_operands={1: 4, 2: 4},
+        out_specs=qo,
+        scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32),
+                                  pltpu.SemaphoreType.DMA((2,))],
         operands=operands,
-        out_shape=(b, hkv, rows, hd),
+        out_shape=(b, hkv * rows, hd),
     )
 
 
 #: Analyzer metadata: lint-time instantiations of ``build_specs`` covering
 #: the plain, multi-token (rows = q_len * g) and quantized variants.
+_META = dict(build=build_specs, grid_dims=("batch", "page_blocks"),
+             dma_index_operands=(0,))
 KERNEL_META = {
     "paged_attention": dict(
-        build=build_specs,
-        lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8, n_pages=4,
-                         quantized=False),
-        grid_dims=("batch", "pages"),
-        sequential_dim=1,
-    ),
+        _META, lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8,
+                                n_pages=4, quantized=False)),
     "paged_attention_multi": dict(
-        build=build_specs,
-        lint_shapes=dict(b=2, hkv=2, rows=12, hd=8, nb=9, bs=8, n_pages=4,
-                         quantized=False),
-        grid_dims=("batch", "pages"),
-        sequential_dim=1,
-    ),
+        _META, lint_shapes=dict(b=2, hkv=2, rows=12, hd=8, nb=9, bs=8,
+                                n_pages=4, quantized=False)),
     "paged_attention_quant": dict(
-        build=build_specs,
-        lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8, n_pages=4,
-                         quantized=True),
-        grid_dims=("batch", "pages"),
-        sequential_dim=1,
-    ),
+        _META, lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8,
+                                n_pages=4, quantized=True)),
 }
 
 
@@ -225,18 +330,17 @@ def _paged_call(
     nb, bs, _, _ = k_pool.shape
     n_pages = page_table.shape[1]
     quantized = k_scale is not None
+    sp = build_specs(b, hkv, rows, hd, nb, bs, n_pages, quantized=quantized,
+                     pool_dtype=k_pool.dtype)
     kern = functools.partial(
-        _paged_kernel, n_pages=n_pages, block_size=bs, q_len=q_len,
+        _paged_kernel, n_pages=n_pages, pages=sp["pages"], q_len=q_len,
         group=group, window=window, softcap=softcap, scale=scale,
         quantized=quantized)
 
-    sp = build_specs(b, hkv, rows, hd, nb, bs, n_pages, quantized=quantized)
-    inputs = [page_table.astype(jnp.int32), cur_len.astype(jnp.int32), qr,
-              k_pool, v_pool]
+    inputs = [page_table.astype(jnp.int32), cur_len.astype(jnp.int32),
+              qr.reshape(b, hkv * rows, hd), k_pool, v_pool]
     if quantized:
-        # A free view of the quant.py scale pool whose last two block dims
-        # are whole (1, Hkv) — the tiling rule's "equal to the array" case.
-        inputs += [k_scale.reshape(nb, 1, hkv), v_scale.reshape(nb, 1, hkv)]
+        inputs += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=sp["num_scalar_prefetch"],
@@ -246,15 +350,18 @@ def _paged_call(
         scratch_shapes=sp["scratch_shapes"],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(sp["out_shape"], qr.dtype),
+        # Rows run in order: a row's last live step starts the next row's
+        # first copies.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(*inputs)
+    return out.reshape(b, hkv, rows, hd)
 
 
 def paged_attention_kernel(

@@ -96,6 +96,57 @@ def test_size_one_head_block_trips_krn001_tiling():
     assert "tiling" in findings[0].message
 
 
+def _hbm_layout(hbm_operands):
+    """A one-operand layout whose operand stays in HBM (``pl.ANY``) and
+    whose page-table prefetch only the body's copies read."""
+    from jax.experimental import pallas as pl
+
+    def build():
+        return dict(grid=(2, 4), num_scalar_prefetch=1,
+                    prefetch_index_operands=(0,),
+                    in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                    hbm_operands=hbm_operands,
+                    out_specs=pl.BlockSpec((1, 8, 128),
+                                           lambda b, j, pt: (b, 0, 0)),
+                    operands=[(9, 8, 2, 128)], out_shape=(2, 8, 128))
+    return build
+
+
+@pytest.mark.parametrize("hbm_operands,found", [
+    ({0: 4}, []),
+    # undeclared: the operand is flagged, and with no HBM operand declared
+    # the DMA-indexed prefetch has nothing to read it
+    ({}, [("KRN001", "in[0]"), ("KRN002", "prefetch[0]")]),
+    ({0: 3}, [("KRN001", "in[0]")])])
+def test_hbm_operand_is_declared_with_its_rank(hbm_operands, found):
+    findings = kernelcheck.check_layout("hbm_kernel", dict(
+        build=_hbm_layout(hbm_operands), lint_shapes={},
+        dma_index_operands=(0,)))
+    assert [(f.rule, f.target) for f in findings] == [
+        (rule, f"hbm_kernel:{what}") for rule, what in found]
+
+
+def test_dma_indexed_prefetch_needs_a_copy_to_read_it():
+    """A prefetch declared DMA-indexed in a layout with no HBM operand is
+    as dead as one no index map reads, and so is an undeclared one."""
+    from jax.experimental import pallas as pl
+
+    def build():
+        return dict(grid=(2, 4), num_scalar_prefetch=1,
+                    prefetch_index_operands=(0,),
+                    in_specs=[pl.BlockSpec((1, 8, 128),
+                                           lambda b, j, pt: (b, 0, 0))],
+                    out_specs=pl.BlockSpec((1, 8, 128),
+                                           lambda b, j, pt: (b, 0, 0)),
+                    operands=[(2, 8, 128)], out_shape=(2, 8, 128))
+
+    for meta in (dict(build=build, lint_shapes={}, dma_index_operands=(0,)),
+                 dict(build=_hbm_layout({0: 4}), lint_shapes={})):
+        findings = kernelcheck.check_layout("dead_prefetch", meta)
+        assert [(f.rule, f.target) for f in findings] == [
+            ("KRN002", "dead_prefetch:prefetch[0]")]
+
+
 def test_fixture_refcount_leak_trips_pool001():
     kv = _small_pool()
     assert kv.alloc(0, 20)

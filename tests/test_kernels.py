@@ -7,7 +7,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, quant, ref
+from repro.kernels import paged_attention as pa
 from repro.models import attention as A
 
 
@@ -125,6 +126,97 @@ class TestPagedAttentionKernel:
         want = ref.paged_attention_ref(q, kp, vp, pt, cl, scale=0.25)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+    def test_default_rule_spans_blocks(self):
+        """The P the shapes give (``pages_per_block``) with a table of
+        several blocks: 16-row pages, 37 of them."""
+        bs, n = 16, 37
+        p = pa.pages_per_block(bs, n)
+        assert 1 < p < n and n % p
+        ks = jax.random.split(jax.random.PRNGKey(2), 4)
+        q = jax.random.normal(ks[0], (3, 4, 16))
+        kp = jax.random.normal(ks[1], (3 * n + 1, bs, 2, 16))
+        vp = jax.random.normal(ks[2], (3 * n + 1, bs, 2, 16))
+        pt = (1 + jax.random.permutation(ks[3], 3 * n)).reshape(3, n)
+        cl = jnp.array([p * bs - 1, p * bs, n * bs - 1], jnp.int32)
+        out = ops.paged_attention(q, kp, vp, pt.astype(jnp.int32), cl,
+                                  scale=0.25)
+        want = ref.paged_attention_ref(q, kp, vp, pt, cl, scale=0.25)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+class TestPagedAttentionBlocks:
+    """P pages per grid step: the table spans several compute blocks, the
+    last one partial, and a block copies only the pages live for its row.
+    Rows sit at the block edges: ``cur_len`` 0, the last position of block
+    0 (P * bs - 1), the first of block 1 (P * bs), in the table's last
+    page, and a free row whose whole table points at the trash page."""
+
+    BS, N_PAGES, P = 4, 10, 3  # blocks of 3, 3, 3 and 1 page
+    HKV, G, HD = 2, 2, 16
+
+    @pytest.fixture(autouse=True)
+    def _blocks_of_p_pages(self, monkeypatch):
+        monkeypatch.setattr(pa, "KEYS_PER_BLOCK", self.P * self.BS)
+
+    def _case(self, seed, t):
+        bs, n, p = self.BS, self.N_PAGES, self.P
+        cur = np.array([0, p * bs - 1, p * bs, n * bs - 2, 0], np.int32)
+        b = len(cur)
+        nb = 1 + (b - 1) * n
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        shape = (b, t, self.HKV * self.G, self.HD) if t > 1 else (
+            b, self.HKV * self.G, self.HD)
+        q = jax.random.normal(ks[0], shape)
+        kp = jax.random.normal(ks[1], (nb, bs, self.HKV, self.HD))
+        vp = jax.random.normal(ks[2], (nb, bs, self.HKV, self.HD))
+        pt = np.zeros((b, n), np.int32)  # page 0 is the trash page
+        pt[:-1] = 1 + np.asarray(jax.random.permutation(ks[3], nb - 1)
+                                 ).reshape(b - 1, n)
+        return q, kp, vp, jnp.asarray(pt), jnp.asarray(cur)
+
+    @pytest.mark.parametrize("kw", [
+        dict(), dict(window=9), dict(softcap=15.0),
+        dict(window=6, softcap=10.0),
+    ])
+    @pytest.mark.parametrize("variant", ["plain", "multi", "int8", "fp8"])
+    def test_vs_oracle(self, variant, kw):
+        t = 3 if variant == "multi" else 1
+        q, kp, vp, pt, cl = self._case(11, t)
+        scales = {}
+        if variant in ("int8", "fp8"):
+            ksc, vsc = quant.scales_of(kp, variant), quant.scales_of(vp, variant)
+            kp, vp = quant.quantize(kp, ksc, variant), quant.quantize(
+                vp, vsc, variant)
+            scales = dict(k_scale=ksc, v_scale=vsc)
+            want = ref.paged_attention_quant_ref(
+                q, kp, vp, ksc, vsc, pt, cl, scale=0.25, **kw)
+        elif t > 1:
+            want = ref.paged_attention_multi_ref(
+                q, kp, vp, pt, cl, scale=0.25, **kw)
+        else:
+            want = ref.paged_attention_ref(q, kp, vp, pt, cl, scale=0.25, **kw)
+        kern = (pa.paged_attention_multi_kernel if t > 1
+                else pa.paged_attention_kernel)
+        out = kern(q, kp, vp, pt, cl, scale=0.25, interpret=True,
+                   **scales, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("t", [1, 3])
+    def test_matches_model_gather_path(self, t):
+        q, kp, vp, pt, cl = self._case(5, t)
+        qt = q if t > 1 else q[:, None]
+        want = A.paged_decode_attention(qt, kp, vp, pt, cur_len=cl,
+                                        scale=0.25)
+        kern = (pa.paged_attention_multi_kernel if t > 1
+                else pa.paged_attention_kernel)
+        out = kern(qt if t > 1 else q, kp, vp, pt, cl, scale=0.25,
+                   interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want if t > 1 else want[:, 0]),
+            atol=2e-5, rtol=2e-5)
 
 
 class TestPagedAttentionMultiKernel:

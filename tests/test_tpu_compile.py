@@ -11,13 +11,22 @@ may be loaded by one process at a time.
 
 import functools
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels import paged_attention as pa
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from chipbench import devtrace  # noqa: E402
 
 B, H, HKV, HD = 4, 32, 8, 128  # qwen3-4b heads, a 4-slot decode batch
 NUM_BLOCKS, BLOCK_SIZE, N_PAGES = 512, 16, 18
@@ -79,3 +88,28 @@ def test_paged_attention_compiles_for_v5e(variant, one_chip,
         args = (q, pool, pool, table, cur)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_keeps_its_op_name_at_cell_shapes(one_chip,
+                                                          no_persistent_cache):
+    """qwen3-4b's decode batch as the benchmark serves it: 32 rows, a table
+    of 256 pages of 16 rows, a pool of 1,400 blocks. Inside a decode step
+    the kernel must stay one operation whose instruction name reads
+    ``paged_attention`` as the trace reduction parses it: a kernel renamed
+    or split would leave ``paged_attn_roofline`` nothing or part to read."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(q, k, v, t, c):
+        return ops.paged_attention(q, k, v, t, c, scale=1.0 / HD ** 0.5,
+                                   interpret=False) * 2
+
+    pool = shape((1400, BLOCK_SIZE, HKV, HD), jnp.bfloat16)
+    text = jax.jit(step).lower(
+        shape((32, H, HD), jnp.bfloat16), pool, pool,
+        shape((32, 256), jnp.int32), shape((32,), jnp.int32)).compile(
+    ).as_text()
+    kernels = [devtrace.op_name(line.strip().removeprefix("ROOT "))
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels == [devtrace.KERNEL]
