@@ -11,8 +11,9 @@ far) and prints, per rate, the time-to-first-token tail, the gap tail, the
 share of due requests admitted by the close and the queue left: the knee
 is the highest rate whose queue does not grow. ``limits`` runs the cell
 once per seed, all in one process, and prints the widest served-token gap
-of each run beside, with ``--control``, the float8 control's: the two
-readings a limit lies between. Both need the TPU, like ``run.py``.
+of each run beside, with ``--control``, the control's (the family's
+reference a precision lower): the two readings a limit lies between. Both
+need the TPU, like ``run.py``.
 """
 
 from __future__ import annotations

@@ -4,7 +4,10 @@ The system under test is ``StreamedBatchEngine`` (``runtime/serving.py``):
 paged pool in the model's bfloat16, the Pallas paged-attention kernel,
 fused chunked prefill, greedy sampling on the device, no prefix sharing and
 no speculative decode. The engine's own ``Tracer`` is on in every run; its
-spans say when each token reached the host.
+spans say when each token reached the host. Whatever depends on the
+architecture (sizes, the server's model configuration, weights, the plain
+reference, operation counts) comes from the configuration's family module,
+``families/<family>.py``; ``families/dense_gqa.py`` states the contract.
 
 An open loop submits each request when it falls due and calls ``step()``
 while work is pending; a request's time to first token runs from its due
@@ -25,17 +28,17 @@ import json
 import math
 import os
 import shutil
+import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import devtrace, reference, timelines, traffic
-from chipbench import weights as W
-from chipbench.dims import Dims, dims_of, load_config
+from chipbench import devtrace, timelines, traffic
 from chipbench.peaks import peaks_for
 
 
@@ -67,7 +70,8 @@ class Cell:
     name: str
     root: Path
     conf: dict[str, Any]
-    dims: Dims
+    family: ModuleType
+    dims: Any  # the family's sizes object
     mix: dict[str, Any]
     chips: int
     end_to_end: list[dict[str, Any]]
@@ -91,13 +95,34 @@ def load_cell(root: str | os.PathLike, workload: str) -> Cell:
         raise KeyError(f"no workload {workload!r}; known: {sorted(cells)}")
     w = cells[workload]
     entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    conf = load_config(root / entry["file"])
+    conf = json.loads((root / entry["file"]).read_text())
+    family = load_family(root, conf.get("family"))
     mix = traffic.load_mix(root / "chipbench" / "traffic"
                            / f"{w['traffic']}.json")
-    return Cell(name=workload, root=root, conf=conf, dims=dims_of(conf),
-                mix=mix, chips=int(w["chips"]),
+    return Cell(name=workload, root=root, conf=conf, family=family,
+                dims=family.dims(conf), mix=mix, chips=int(w["chips"]),
                 end_to_end=_for_cell(bench["end_to_end"], workload),
                 per_layer=_for_cell(bench["per_layer"], workload))
+
+
+def load_family(root: str | os.PathLike, name: str | None) -> ModuleType:
+    """``<root>/chipbench/families/<name>.py``, the module through which a
+    run reaches the architecture (contract in ``families/dense_gqa.py``)."""
+    d = Path(root) / "chipbench" / "families"
+    known = sorted(p.stem for p in d.glob("*.py"))
+    if name not in known:
+        raise ValueError(f"unknown architecture family {name!r}; "
+                         f"known: {known}")
+    return _load_module(f"chipbench_family_{name}", d / f"{name}.py")
+
+
+def _load_module(name: str, path: Path) -> ModuleType:
+    """The module at ``path``, executed under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(root: Path, metric: str):
@@ -107,54 +132,33 @@ def reader(root: Path, metric: str):
     path = d / f"{metric}.py"
     if not path.exists():
         path = d / f"{metric.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(f"chipbench_metric_{metric.replace('.', '_')}",
+                        path).read
 
 
 # -- the server ---------------------------------------------------------------
 
 
-def model_config(d: Dims):
-    """The server's ``ModelConfig`` for these sizes, in bfloat16. Refuses
-    an architecture the reference does not compute (only dense GQA with
-    SwiGLU, rotary positions and a tied head)."""
-    from repro import configs
-    from repro.models.transformer import LayerSpec
-
-    base = configs.get_config(d.arch)
-    cfg = dataclasses.replace(
-        base, n_layers=d.n_layers, d_model=d.d_model, n_heads=d.n_heads,
-        n_kv_heads=d.n_kv_heads, head_dim=d.head_dim, d_ff=d.d_ff,
-        vocab_size=d.vocab, rope_theta=d.rope_theta, qk_norm=d.qk_norm,
-        tie_embeddings=d.tied, param_dtype=jnp.bfloat16,
-        compute_dtype=jnp.bfloat16)
-    plain = (cfg.layer_unit == (LayerSpec(mixer="attn", ffn="dense"),)
-             and cfg.ffn_kind == "swiglu" and cfg.use_rope
-             and not cfg.sinusoidal_pos and not cfg.sliding_window
-             and not cfg.attn_softcap and not cfg.final_softcap
-             and not cfg.sandwich_norm and not cfg.embed_scale
-             and cfg.query_scale is None and cfg.tie_embeddings
-             and not cfg.prefix_len and not cfg.is_encoder_decoder)
-    if not plain or d.rotary_fraction != 1.0 or d.norm_eps != 1e-6:
-        raise ValueError(f"{d.arch}: the server and the reference differ "
-                         "for this architecture")
-    return cfg
+#: ``server`` keys of a configuration file that the harness reads itself;
+#: any other key goes to ``ServeConfig`` under its own name.
+SERVER_KEYS = ("max_seq", "prefill_chunk", "max_batch", "block_size",
+               "num_blocks", "chunk_jit_cap", "page_jit_cap")
+#: What every cell serves with; a configuration file may not set these.
+SERVER_FIXED = {"paged": True, "paged_kernel": True, "fused_prefill": True,
+                "temperature": 0.0}
 
 
 def build_engine(cell: Cell, cfg, params, tracer):
     from repro.runtime.serving import ServeConfig, StreamedBatchEngine
 
     s = cell.server
+    fixed = sorted(set(s) & {*SERVER_FIXED, "max_new_tokens"})
+    if fixed:
+        raise ValueError(f"{cell.name}: the harness sets {fixed} itself")
     scfg = ServeConfig(
-        max_seq=s["max_seq"], prefill_chunk=s["prefill_chunk"],
+        **{k: s[k] for k in SERVER_KEYS}, **SERVER_FIXED,
         max_new_tokens=int(cell.mix["output"]["max"]),
-        max_batch=s["max_batch"], paged=True, block_size=s["block_size"],
-        num_blocks=s["num_blocks"], paged_kernel=True, fused_prefill=True,
-        temperature=0.0, chunk_jit_cap=s["chunk_jit_cap"],
-        page_jit_cap=s["page_jit_cap"])
+        **{k: v for k, v in s.items() if k not in SERVER_KEYS})
     eng = StreamedBatchEngine(cfg, params, scfg, tracer=tracer)
     pool_dt = {x.dtype for x in jax.tree.leaves(eng.kv.pools)}
     if pool_dt != {jnp.dtype(jnp.bfloat16)} or not eng.scfg.fused_prefill:
@@ -372,17 +376,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
              peak_table: dict | None = None, control: bool = False,
              check: bool = True, keep: dict | None = None) -> dict:
     """Set up, drive one window, read it and check it; the result line.
-    ``control`` also reads the float8 control's gaps, for setting the
-    limit; benchmark runs leave it off. ``check=False`` skips the reference
-    and adds ``sweep``: what a rate sweep reads of the window. ``keep``, a
-    dict, is given the run's record under ``"run"``."""
+    ``control`` also reads the family's control (its reference a precision
+    lower), for setting the limit; benchmark runs leave it off.
+    ``check=False`` skips the reference and adds ``sweep``: what a rate
+    sweep reads of the window. ``keep``, a dict, is given the run's record
+    under ``"run"``."""
     from repro.obs import MetricsRegistry, Tracer
 
-    d, mix = cell.dims, cell.mix
+    d, mix, family = cell.dims, cell.mix, cell.family
     dev = jax.devices()[0]
     peaks = peaks_for(dev.device_kind, peak_table)
-    cfg = model_config(d)
-    params = W.make_program_params(d, cfg.padded_vocab, seed)
+    cfg = family.program_config(d)
+    params = family.program_params(d, cfg.padded_vocab, seed)
     tracer = Tracer(capacity=1 << 22, enabled=True)
     eng = build_engine(cell, cfg, params, tracer)
     period = traffic.period_size(mix, seconds, cell.server["max_batch"])
@@ -494,8 +499,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
         return result
     t_ref = time.perf_counter()
     rows = -(-int(mix["output"]["max"]) // 128) * 128
-    gaps = reference.served_gaps(d, seed, [sample[u] for u in sorted(sample)],
-                                 rows=rows, control=control)
+    gaps = family.served_gaps(d, seed, [sample[u] for u in sorted(sample)],
+                              rows=rows, control=control)
     worst = float(np.max(gaps["program"]))
     limit = float(cell.conf["limits"]["logit_gap"])
     log(f"reference: {len(sample)} requests, {gaps['program'].size} served "

@@ -36,7 +36,7 @@ def main() -> int:
                       os.path.join(ROOT, "chipbench", ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     from chipbench import devtrace, harness, traffic
-    from chipbench.dims import dims_of, load_config
+    from chipbench.dims import load_config
 
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
@@ -46,9 +46,10 @@ def main() -> int:
     per_layer = [{k: m[k] for k in ("name", "unit")}
                  for m in bench["per_layer"]
                  if m["name"] != "preemptions"]
+    family = harness.load_family(ROOT, conf["family"])
     cell = harness.Cell(
         name="tiny.open", root=harness.Path(ROOT), conf=conf,
-        dims=dims_of(conf),
+        family=family, dims=family.dims(conf),
         mix=traffic.load_mix(os.path.join(DATA, "tiny_open.json")),
         chips=1, end_to_end=[], per_layer=per_layer)
     read = harness._Profiler.read

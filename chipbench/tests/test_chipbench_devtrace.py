@@ -15,7 +15,7 @@ import pytest  # noqa: E402
 
 from chipbench import devtrace, harness, timelines, traffic  # noqa: E402
 from chipbench.devtrace import DeviceTrace, Event  # noqa: E402
-from chipbench.dims import dims_of, load_config  # noqa: E402
+from chipbench.dims import load_config  # noqa: E402
 
 DATA = ROOT / "chipbench" / "testdata"
 
@@ -92,8 +92,10 @@ def recorded():
     traces = devtrace.load(DATA / "tiny_trace.xplane.pb.gz", rec["anchor_ns"])
     conf = load_config(DATA / "tiny.json")
     spans = [harness_span(*s) for s in rec["spans"]]
+    family = harness.load_family(ROOT, conf["family"])
     cell = harness.Cell(
-        name="tiny.open", root=ROOT, conf=conf, dims=dims_of(conf),
+        name="tiny.open", root=ROOT, conf=conf, family=family,
+        dims=family.dims(conf),
         mix=traffic.load_mix(DATA / "tiny_open.json"), chips=1,
         end_to_end=[], per_layer=[])
     run = harness.Run(
@@ -155,6 +157,23 @@ def test_recorded_readings_repeat(recorded):
     dt = run.traces[0]
     _same(devtrace.top_ops(dt), rec["breakdown"]["device_ops"])
     _same(devtrace.idle_gaps(dt, run.spans), rec["breakdown"]["idle_gaps"])
+
+
+# What the readers gave on the recorded trace before the architecture was
+# reached through a family module; they must give it again, to the bit.
+PINNED = {
+    "mfu": 0.01700348373535005,
+    "paged_attn_roofline": 1.7223197711002585,
+    "prefill_ms_per_ktok": 0.7745843750000001,
+    "decode_step_ms.open": 0.059738428571428574,
+    "decode_step_ms.offline": 0.059738428571428574,
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_recorded_readings_are_pinned(recorded, name):
+    _, run = recorded
+    assert harness.reader(ROOT, name)(run) == PINNED[name]
 
 
 def test_recorded_roofline_and_mfu_are_shares(recorded):

@@ -1,10 +1,11 @@
 """A whole run of the harness on the CPU at a tiny size, past the look for a
 chip: traffic, engine, window, readers, result line and the check against
-the plain reference. The cell's configuration, traffic mix and one metric
-exist only in this test (``minicell``), as files and ``BENCHMARK.json``
-entries, with no edit to a file under ``chipbench/``: the harness finds
-them by name."""
+the plain reference. The cell's configuration, traffic mix, architecture
+family and one metric exist only in this test (``minicell``), as files and
+``BENCHMARK.json`` entries, with no edit to a file under ``chipbench/``:
+the harness finds them by name."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -21,14 +22,24 @@ from chipbench import harness  # noqa: E402
 from minicell import MIX, ROOT, make_root, run  # noqa: E402
 
 
+def _files(d: Path) -> dict[str, str]:
+    """Digest of every file under ``d``, caches left out."""
+    return {str(p.relative_to(d)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in d.rglob("*") if p.is_file() and not any(
+                part.startswith(".") or part == "__pycache__"
+                for part in p.relative_to(d).parts)}
+
+
 @pytest.fixture(scope="module")
 def rehearsal(tmp_path_factory):
+    before = _files(ROOT / "chipbench")
     root = make_root(tmp_path_factory.mktemp("checkout"))
-    return run(root)
+    out, r = run(root)
+    return out, r, before
 
 
 def test_result_line_shape(rehearsal):
-    out, _ = rehearsal
+    out, _, _ = rehearsal
     line = json.loads(json.dumps(out))
     assert list(line)[-1] == "check"
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
@@ -42,12 +53,39 @@ def test_result_line_shape(rehearsal):
 
 
 def test_window_is_served_and_checked(rehearsal):
-    out, r = rehearsal
+    out, r, _ = rehearsal
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] == len(r.window_uids) > 10
     assert r.compiles_in_window == (0, 0)
     assert out["metrics"]["admitted"]["value"] > 0
     assert 0 <= out["check"]["logit_gap"]["value"] <= 0.01
+
+
+def test_a_new_family_needs_only_new_files(rehearsal):
+    out, r, before = rehearsal
+    assert out["correct"] is True
+    # The harness built, weighed and checked the server through the family
+    # file the test wrote into the checkout, once each.
+    assert r.cell.family.__name__ == "chipbench_family_mini_gqa"
+    assert r.cell.family.CALLS == ["program_config", "program_params",
+                                   "served_gaps"]
+    assert not (ROOT / "chipbench" / "families" / "mini_gqa.py").exists()
+    assert _files(ROOT / "chipbench") == before
+
+
+@pytest.mark.parametrize("family", ["no_such_family", None])
+def test_unknown_family_fails_at_load_cell(tmp_path, family):
+    root = make_root(tmp_path)
+    path = root / "chipbench" / "configs" / "mini.json"
+    conf = json.loads(path.read_text())
+    if family is None:
+        del conf["family"]
+    else:
+        conf["family"] = family
+    path.write_text(json.dumps(conf))
+    with pytest.raises(ValueError, match="unknown architecture family") as e:
+        harness.load_cell(root, "mini.burst")
+    assert "['dense_gqa', 'mini_gqa']" in str(e.value)
 
 
 def test_closed_loop_keeps_every_slot_busy(tmp_path):
