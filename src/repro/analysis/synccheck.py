@@ -18,7 +18,9 @@ For each ``ServableModel`` arch x serving mode the audit builds a real
 Hot paths per engine: the batched decode tick, the speculative verify
 step, the prefill-chunk step (legacy and fused), and the page
 scatter/gather.  The audited modes are the ones ``validate_arch``
-accepts (quant / fused prefill / speculation are transformer-only).
+accepts (quant / speculation are transformer-only; fused prefill is the
+paged default for transformer and mamba, and whisper's only paged mode
+is the legacy one).
 """
 
 from __future__ import annotations
@@ -44,8 +46,9 @@ ARCH_SMOKE = {
     "whisper": "whisper-medium",
 }
 
-#: Serving modes per arch; quant/fused/spec are transformer-only
-#: (``ServeConfig.validate_arch`` rejects them elsewhere).
+#: Serving modes per arch; quant/spec and the legacy paged prefill beside
+#: the fused one are audited for the transformer only
+#: (``ServeConfig.validate_arch`` rejects quant/spec elsewhere).
 ARCH_MODES = {
     "transformer": ("contiguous", "paged", "paged_legacy", "quant", "spec"),
     "mamba": ("contiguous", "paged"),
@@ -274,7 +277,7 @@ def audit_engine(eng, arch: str, mode: str) -> tuple[list[Finding],
         n_ctx = eng.kv.pages_for(chunk)
         pf_args = [("params", eng.params), (car, eng.kv.pools),
                    ("page_table", jnp.zeros((1, n_ctx), jnp.int32)),
-                   ("prompt", tokens)]
+                   ("prompt", tokens), ("slot", jnp.int32(0))]
         pf_fn = eng.single._fused_chunk_fn(chunk, 0)
         pf_builder = type(eng.single)._fused_chunk_fn
     else:

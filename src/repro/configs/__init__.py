@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned architectures as selectable configs.
+"""Architecture registry: the assigned architectures as selectable configs.
 
 Use ``get_config("<arch-id>")`` / ``--arch <arch-id>`` in the launchers.
 """
@@ -22,6 +22,7 @@ _MODULES: dict[str, str] = {
     "mamba2-2.7b": "mamba2_2_7b",
     "paligemma-3b": "paligemma_3b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 

@@ -492,6 +492,7 @@ def attention_apply(
     q_offset: int = 0,  # static chunk offset for streamed (chunked) prefill
     page_table: jax.Array | None = None,  # paged decode: (B, n_pages) int32
     paged_kernel: bool = False,  # paged decode via the Pallas pool kernel
+    norm_eps: float = 1e-6,  # the q/k RMSNorm's epsilon
 ) -> tuple[jax.Array, dict[str, jax.Array] | None]:
     """Returns (output (B,S,D), updated cache or None)."""
     b, s, d = x.shape
@@ -507,8 +508,8 @@ def attention_apply(
         v = (kv_in @ p["wv"]).reshape(b, kv_in.shape[1], n_kv_heads, head_dim)
 
     if qk_norm:
-        q = layers.rmsnorm(p["q_norm"], q)
-        k = layers.rmsnorm(p["k_norm"], k)
+        q = layers.rmsnorm(p["q_norm"], q, eps=norm_eps)
+        k = layers.rmsnorm(p["k_norm"], k, eps=norm_eps)
 
     if positions is not None and kv_source is None:
         sin, cos = layers.rope_angles(positions, head_dim, rope_theta)

@@ -202,8 +202,10 @@ def mamba_apply(
     state: jax.Array | None = None,
     conv_state: jax.Array | None = None,  # (B, W-1, conv_dim) decode carry
     decode: bool = False,
+    norm_eps: float = 1e-6,  # the gated RMSNorm's epsilon
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """Full block. Returns (out (B,S,D), cache {"ssm","conv"})."""
+    """Full block. Returns (out (B,S,D), cache {"ssm","conv"}); the SSM
+    state is updated and returned in float32, whatever it is stored in."""
     bsz, s, d_model = u.shape
     d_inner, n_heads, conv_dim = mamba_dims(d_model, expand=expand, headdim=headdim, d_state=d_state)
 
@@ -229,13 +231,14 @@ def mamba_apply(
     b_ = conv[..., d_inner : d_inner + d_state]
     c_ = conv[..., d_inner + d_state :]
 
-    a = -jnp.exp(p["A_log"])  # (H,) negative
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, None])
+    a = -jnp.exp(p["A_log"].astype(jnp.float32))  # (H,) negative
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32)[None, None])
 
     if decode:
         assert state is not None
         y_t, new_state = ssd_decode_step(
-            state, x[:, 0], dt[:, 0], a, b_[:, 0], c_[:, 0]
+            state.astype(jnp.float32), x[:, 0], dt[:, 0], a, b_[:, 0], c_[:, 0]
         )
         y = y_t[:, None]
     else:
@@ -244,7 +247,7 @@ def mamba_apply(
 
     y = y + p["D"][None, None, :, None].astype(y.dtype) * x  # skip connection
     y = y.reshape(bsz, s, d_inner)
-    y = layers.rmsnorm(p["norm"], y * jax.nn.silu(z))
+    y = layers.rmsnorm(p["norm"], y * jax.nn.silu(z), eps=norm_eps)
     out = y @ p["out_proj"]
     return out, {"ssm": new_state, "conv": new_conv_state}
 

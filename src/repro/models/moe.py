@@ -10,7 +10,13 @@ dispatch tensor of one chunk is in flight while the previous chunk computes
 -- the same pipeline the paper builds with hStreams tasks.
 
 Includes shared experts (qwen2-moe: dense experts always active, sigmoid
-gated) and an auxiliary load-balancing loss (Switch-style).
+gated; granite: ungated) and an auxiliary load-balancing loss
+(Switch-style).
+
+Serving uses ``moe_apply_held`` instead: dropless, and told which experts
+the layer holds (expert parallelism's share of a layer). The router scores
+every expert; the layer computes its own experts' part of the result for
+the tokens routed to them, and the absent experts add nothing.
 """
 
 from __future__ import annotations
@@ -37,14 +43,18 @@ def moe_init(
     dtype=jnp.float32,
     expert_shards: int = 1,
     n_experts_pad: int | None = None,
+    n_held: int = 0,
+    shared_gate: bool = True,
 ) -> Params:
     """``expert_shards``: store each expert as ``s`` half-width virtual
     experts (E*s, D, F/s) so EP divides the mesh axis (mixtral 8x2=16).
     ``n_experts_pad``: allocate dead expert slots so the stored expert count
-    divides the axis (qwen2-moe 60 -> 64); the router never selects them."""
+    divides the axis (qwen2-moe 60 -> 64); the router never selects them.
+    ``n_held``: store only this layer's share of the experts (0 = all).
+    ``shared_gate``: gate the shared MLP by a sigmoid of the token."""
     ks = jax.random.split(key, 6)
     std = 1.0 / math.sqrt(d_model)
-    e_store = (n_experts_pad or n_experts) * expert_shards
+    e_store = (n_held or n_experts_pad or n_experts) * expert_shards
     f_shard = d_ff // expert_shards
     assert d_ff % expert_shards == 0, (d_ff, expert_shards)
     p: Params = {
@@ -57,8 +67,21 @@ def moe_init(
     if n_shared_experts > 0:
         sd = shared_d_ff if shared_d_ff is not None else n_shared_experts * d_ff
         p["shared"] = layers.ffn_init(ks[4], d_model, sd, dtype, kind="swiglu")
-        p["shared_gate"] = layers.dense_init(ks[5], (d_model, 1), dtype, scale=std)
+        if shared_gate:
+            p["shared_gate"] = layers.dense_init(
+                ks[5], (d_model, 1), dtype, scale=std)
     return p
+
+
+def shared_apply(p: Params, tokens: jax.Array) -> jax.Array:
+    """The shared MLP every token passes, gated when the layer has a
+    ``shared_gate``; zero without shared experts."""
+    if "shared" not in p:
+        return jnp.zeros_like(tokens)
+    y = layers.ffn_apply(p["shared"], tokens, kind="swiglu")
+    if "shared_gate" in p:
+        y = jax.nn.sigmoid(tokens @ p["shared_gate"]) * y
+    return y
 
 
 def route_topk(
@@ -219,8 +242,7 @@ def moe_apply(
         else:
             y, aux = one_chunk_gather(tokens, logits)
         if "shared" in p:
-            gate = jax.nn.sigmoid(tokens @ p["shared_gate"])
-            y = y + gate * layers.ffn_apply(p["shared"], tokens, kind="swiglu")
+            y = y + shared_apply(p, tokens)
         return carry + aux, y.reshape(b, chunk, d)
 
     aux_total, yc = jax.lax.scan(one_chunk, jnp.float32(0.0), xc)
@@ -248,6 +270,45 @@ def moe_ref_dense(p: Params, x: jax.Array, *, top_k: int) -> jax.Array:
     )  # (N, k, D)
     y = (sel * gate_vals[..., None].astype(sel.dtype)).sum(1)
     if "shared" in p:
-        gate = jax.nn.sigmoid(tokens @ p["shared_gate"])
-        y = y + gate * layers.ffn_apply(p["shared"], tokens, kind="swiglu")
+        y = y + shared_apply(p, tokens)
     return y.reshape(b, s, d)
+
+
+def moe_apply_held(
+    p: Params,
+    x: jax.Array,  # (B, S, D)
+    *,
+    top_k: int,
+    offset: int = 0,  # the first expert this layer holds
+    expert_shards: int = 1,
+) -> tuple[jax.Array, jax.Array]:
+    """Dropless expert layer over the experts it holds.
+
+    Routes each token over every expert (top-k of the router's logits,
+    gates the softmax over the k selected logits) and computes the part of
+    the result that the held experts ``[offset, offset + held)`` give, plus
+    the shared MLP. Every held expert runs on every token (capacity = the
+    tokens), weighted by the token's gate for it, 0 where the token did not
+    pick it: nothing is dropped, and a token's output does not depend on
+    the other tokens of the batch. Returns (output (B, S, D), the (B, S)
+    count of each token's picks that this layer holds).
+    """
+    b, s, d = x.shape
+    tokens = x.reshape(b * s, d)
+    logits = tokens.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    top, idx = jax.lax.top_k(logits, top_k)
+    gates = jax.nn.softmax(top, axis=-1)  # (N, k)
+    held = p["wi"].shape[0] // expert_shards
+    local = idx - offset
+    here = (local >= 0) & (local < held)  # (N, k)
+    pick = jax.nn.one_hot(jnp.where(here, local, held), held,
+                          dtype=jnp.float32)  # out-of-share picks: all zero
+    weight = jnp.einsum("nk,nke->ne", gates, pick)  # (N, held)
+    if expert_shards > 1:  # each virtual half-expert takes its expert's gate
+        weight = jnp.repeat(weight, expert_shards, axis=1)
+    h = jax.nn.silu(jnp.einsum("nd,edf->enf", tokens, p["wg"])) * jnp.einsum(
+        "nd,edf->enf", tokens, p["wi"])
+    ye = jnp.einsum("enf,efd->end", h, p["wo"])
+    y = jnp.einsum("end,ne->nd", ye.astype(jnp.float32), weight)
+    y = y.astype(x.dtype) + shared_apply(p, tokens)
+    return y.reshape(b, s, d), here.sum(-1).astype(jnp.int32).reshape(b, s)

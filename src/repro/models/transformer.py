@@ -80,12 +80,19 @@ class ModelConfig:
     moe_impl: str = "gather"  # "gather" (optimized) | "einsum" (baseline)
     expert_shards: int = 1  # virtual-expert TP folded into EP
     n_experts_pad: int | None = None  # dead expert slots for EP divisibility
+    shared_gate: bool = True  # qwen2-moe's sigmoid gate on the shared MLP
+    # Expert parallelism's share of a layer: the router scores all
+    # n_experts, this layer holds experts [0, experts_held) and computes
+    # their part of the result (0 = all held).
+    experts_held: int = 0
 
     # mamba
     ssm_state: int = 128
     mamba_headdim: int = 64
     mamba_expand: int = 2
     ssd_chunk: int = 64
+    ssm_cache_dtype: Any = jnp.float32  # per-slot SSM state as stored; the
+    # update itself always runs in float32
 
     # encoder-decoder (whisper)
     n_encoder_layers: int = 0
@@ -98,6 +105,13 @@ class ModelConfig:
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma: h *= sqrt(d_model)
     vocab_pad_to: int = 256
+    # granite: h = embed_multiplier * E[tok]; each sublayer's output enters
+    # the residual times residual_multiplier; logits are divided by
+    # logits_scaling
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6  # every RMSNorm of the model
 
     # compute
     param_dtype: Any = jnp.bfloat16
@@ -185,7 +199,8 @@ def _layer_init(cfg: ModelConfig, spec: LayerSpec, key) -> Params:
             ks[2], d_model=cfg.d_model, d_ff=cfg.expert_d_ff or cfg.d_ff,
             n_experts=cfg.n_experts, n_shared_experts=cfg.n_shared_experts,
             shared_d_ff=cfg.shared_d_ff, dtype=dt,
-            expert_shards=cfg.expert_shards, n_experts_pad=cfg.n_experts_pad)
+            expert_shards=cfg.expert_shards, n_experts_pad=cfg.n_experts_pad,
+            n_held=cfg.experts_held, shared_gate=cfg.shared_gate)
     return p
 
 
@@ -253,7 +268,8 @@ def init_cache(cfg: ModelConfig, bsz: int, max_seq: int, *, enc_seq: int | None 
             d_inner, n_heads, conv_dim = mamba.mamba_dims(
                 cfg.d_model, expand=cfg.mamba_expand, headdim=cfg.mamba_headdim,
                 d_state=cfg.ssm_state)
-            c["ssm"] = jnp.zeros((r, bsz, n_heads, cfg.mamba_headdim, cfg.ssm_state), jnp.float32)
+            c["ssm"] = jnp.zeros((r, bsz, n_heads, cfg.mamba_headdim, cfg.ssm_state),
+                                 cfg.ssm_cache_dtype)
             c["conv"] = jnp.zeros((r, bsz, mamba.CONV_WIDTH - 1, conv_dim), dt)
         if spec.cross_attn:
             es = enc_seq or cfg.encoder_seq
@@ -303,7 +319,8 @@ def init_paged_cache(
                 cfg.d_model, expand=cfg.mamba_expand, headdim=cfg.mamba_headdim,
                 d_state=cfg.ssm_state)
             c["ssm"] = jnp.zeros(
-                (r, bsz, n_heads, cfg.mamba_headdim, cfg.ssm_state), jnp.float32)
+                (r, bsz, n_heads, cfg.mamba_headdim, cfg.ssm_state),
+                cfg.ssm_cache_dtype)
             c["conv"] = jnp.zeros((r, bsz, mamba.CONV_WIDTH - 1, conv_dim), dt)
         if spec.cross_attn:
             shape = (r, bsz, cfg.encoder_seq, cfg.n_kv_heads, cfg.head_dim)
@@ -333,16 +350,27 @@ def _apply_layer(
     q_offset: int = 0,
     page_table: jax.Array | None = None,
     paged_kernel: bool = False,
-) -> tuple[jax.Array, Params, jax.Array]:
-    """One layer. Returns (h, new_cache, aux_loss)."""
+) -> tuple[jax.Array, Params, jax.Array, jax.Array | None]:
+    """One layer. Returns (h, new_cache, aux_loss, held pairs): the last is
+    the (B, S) count of each token's routed experts that this layer holds
+    (None without a served expert layer)."""
     aux = jnp.float32(0.0)
+    held = None
     new_cache: Params = dict(cache) if cache is not None else None
+
+    def norm(name, x):
+        return layers.rmsnorm(p[name], x, eps=cfg.norm_eps)
+
+    def residual(resid, out):
+        if cfg.residual_multiplier != 1.0:
+            out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+        return resid + out
 
     if spec.mixer in ("attn", "attn_local"):
         window = cfg.sliding_window if spec.mixer == "attn_local" else (
             cfg.sliding_window if all(s.mixer != "attn_local" for s in cfg.layer_unit) else 0)
         resid = h
-        x = layers.rmsnorm(p["mixer_norm"], h)
+        x = norm("mixer_norm", h)
         kv_cache = None
         if cache is not None and "k" in cache:
             kv_cache = {key: cache[key]
@@ -356,31 +384,40 @@ def _apply_layer(
             prefix_len=prefix_len, softcap_val=cfg.attn_softcap,
             scale=cfg.query_scale, chunk=cfg.attn_chunk, qk_norm=cfg.qk_norm,
             cache=kv_cache, cur_len=cur_len, q_offset=q_offset,
-            page_table=page_table, paged_kernel=paged_kernel)
+            page_table=page_table, paged_kernel=paged_kernel,
+            norm_eps=cfg.norm_eps)
         if cfg.sandwich_norm:
-            out = layers.rmsnorm(p["post_mixer_norm"], out)
-        h = resid + out
+            out = norm("post_mixer_norm", out)
+        h = residual(resid, out)
         if upd is not None and new_cache is not None:
             for key in ("k", "v", "k_scale", "v_scale"):
                 if key in upd:
                     new_cache[key] = upd[key]
     elif spec.mixer == "mamba":
         resid = h
-        x = layers.rmsnorm(p["mixer_norm"], h)
+        x = norm("mixer_norm", h)
         decode = cur_len is not None
         out, upd = mamba.mamba_apply(
             p["mixer"], x, headdim=cfg.mamba_headdim, d_state=cfg.ssm_state,
             expand=cfg.mamba_expand, chunk=cfg.ssd_chunk,
             state=cache["ssm"] if (cache is not None and "ssm" in cache) else None,
             conv_state=cache["conv"] if (cache is not None and "conv" in cache) else None,
-            decode=decode)
-        h = resid + out
+            decode=decode, norm_eps=cfg.norm_eps)
+        h = residual(resid, out)
         if new_cache is not None:
-            new_cache["ssm"], new_cache["conv"] = upd["ssm"], upd["conv"]
+            for key in ("ssm", "conv"):
+                new = upd[key].astype(cache[key].dtype)
+                if decode and jnp.ndim(cur_len) == 1:
+                    # Continuous batching: a free slot (cur 0) keeps its
+                    # state rows, which an admission in flight may be
+                    # filling chunk by chunk through the pool.
+                    live = (cur_len > 0).reshape((-1,) + (1,) * (new.ndim - 1))
+                    new = jnp.where(live, new, cache[key])
+                new_cache[key] = new
 
     if spec.cross_attn:
         resid = h
-        x = layers.rmsnorm(p["cross_norm"], h)
+        x = norm("cross_norm", h)
         b, s, _ = x.shape
         q = (x @ p["cross"]["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
         if cur_len is not None and cache is not None and "cross_k" in cache:
@@ -399,25 +436,33 @@ def _apply_layer(
                 new_cache["cross_k"] = kc.astype(cache["cross_k"].dtype)
                 new_cache["cross_v"] = vc.astype(cache["cross_v"].dtype)
         out = out.reshape(b, s, cfg.n_heads * cfg.head_dim) @ p["cross"]["wo"]
-        h = resid + out
+        h = residual(resid, out)
 
     if spec.ffn == "dense":
         resid = h
-        x = layers.rmsnorm(p["ffn_norm"], h)
+        x = norm("ffn_norm", h)
         out = layers.ffn_apply(p["ffn"], x, kind=cfg.ffn_kind)
         if cfg.sandwich_norm:
-            out = layers.rmsnorm(p["post_ffn_norm"], out)
-        h = resid + out
+            out = norm("post_ffn_norm", out)
+        h = residual(resid, out)
     elif spec.ffn == "moe":
         resid = h
-        x = layers.rmsnorm(p["ffn_norm"], h)
-        out, aux = moe.moe_apply(
-            p["ffn"], x, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            moe_chunk=cfg.moe_chunk, impl=cfg.moe_impl,
-            expert_shards=cfg.expert_shards)
-        h = resid + out
+        x = norm("ffn_norm", h)
+        if cache is not None or cfg.experts_held:
+            # Serving (and any expert-parallel share): dropless, so that a
+            # token's output never depends on its batch neighbours.
+            out, held = moe.moe_apply_held(
+                p["ffn"], x, top_k=cfg.top_k,
+                expert_shards=cfg.expert_shards)
+        else:
+            out, aux = moe.moe_apply(
+                p["ffn"], x, top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+                moe_chunk=cfg.moe_chunk, impl=cfg.moe_impl,
+                expert_shards=cfg.expert_shards)
+        h = residual(resid, out)
 
-    return h, (new_cache if new_cache is not None else {}), aux
+    return h, (new_cache if new_cache is not None else {}), aux, held
 
 
 def _remat_wrap(cfg: ModelConfig, fn):
@@ -445,8 +490,11 @@ def forward_hidden(
     q_offset: int = 0,
     page_table: jax.Array | None = None,
     paged_kernel: bool = False,
+    count_pairs: bool = False,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Run the stacked blocks. Returns (h, new caches, aux loss).
+    """Run the stacked blocks. Returns (h, new caches, aux loss), and with
+    ``count_pairs`` a fourth item: the (B, S) count, over every layer, of
+    each token's routed experts that the layers hold.
 
     ``page_table`` switches attention layers' decode path to the paged KV
     pool (cache leaves are then (r, num_blocks, block_size, hkv, hd)); the
@@ -457,12 +505,12 @@ def forward_hidden(
     block_caches = caches["blocks"] if caches is not None else None
 
     def body(carry, xs):
-        h, aux = carry
+        h, aux, pairs = carry
         bp, bc = xs
         new_bc = {}
         for i, spec in enumerate(unit):
             lc = bc.get(f"layer{i}") if bc is not None else None
-            h, nc, a = _apply_layer(
+            h, nc, a, held = _apply_layer(
                 cfg, spec, bp[f"layer{i}"], h,
                 positions=positions, cache=lc, cur_len=cur_len,
                 enc_out=enc_out, prefix_len=prefix_len, causal=causal,
@@ -474,13 +522,18 @@ def forward_hidden(
             h = meshutil.shard_batch(h)
             new_bc[f"layer{i}"] = nc
             aux = aux + a
-        return (h, aux), new_bc
+            if pairs is not None and held is not None:
+                pairs = pairs + held
+        return (h, aux, pairs), new_bc
 
     body = _remat_wrap(cfg, body)
 
-    (h, aux), new_caches = jax.lax.scan(
-        body, (h, jnp.float32(0.0)), (blocks, block_caches))
+    pairs0 = jnp.zeros(h.shape[:2], jnp.int32) if count_pairs else None
+    (h, aux, pairs), new_caches = jax.lax.scan(
+        body, (h, jnp.float32(0.0), pairs0), (blocks, block_caches))
     out_caches = {"blocks": new_caches} if caches is not None else None
+    if count_pairs:
+        return h, out_caches, aux, pairs
     return h, out_caches, aux
 
 
@@ -488,11 +541,27 @@ def _embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Ar
     h = params["embed"][tokens].astype(cfg.compute_dtype)
     if cfg.embed_scale:
         h = h * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    if cfg.embed_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embed_multiplier, cfg.compute_dtype)
     return h
 
 
 def _unembed(cfg: ModelConfig, params: Params) -> jax.Array:
     return params["embed"] if cfg.tie_embeddings else params["unembed"]
+
+
+def final_norm(cfg: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
+    return layers.rmsnorm(params["final_norm"], h, eps=cfg.norm_eps)
+
+
+def head_logits(cfg: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
+    """float32 logits of final hidden states ``h`` (before the final norm):
+    norm, output head, ``logits_scaling`` and the final softcap."""
+    h = final_norm(cfg, params, h)
+    logits = h.astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return layers.softcap(logits, cfg.final_softcap)
 
 
 def encode(cfg: ModelConfig, params: Params, enc_inputs: jax.Array) -> jax.Array:
@@ -505,7 +574,7 @@ def encode(cfg: ModelConfig, params: Params, enc_inputs: jax.Array) -> jax.Array
     h, _, _ = forward_hidden(
         enc_cfg, params, h, positions=None, causal=False,
         unit=_ENC_UNIT, blocks=params["encoder"]["blocks"])
-    return layers.rmsnorm(params["encoder"]["final_norm"], h)
+    return layers.rmsnorm(params["encoder"]["final_norm"], h, eps=cfg.norm_eps)
 
 
 def _prepare_inputs(
@@ -542,7 +611,9 @@ def train_loss(
     h, _, aux = forward_hidden(
         cfg, params, h, positions=positions, enc_out=enc_out,
         prefix_len=prefix_len, causal=True)
-    h = layers.rmsnorm(params["final_norm"], h)
+    h = final_norm(cfg, params, h)
+    if cfg.logits_scaling != 1.0:
+        h = h / jnp.asarray(cfg.logits_scaling, h.dtype)
     if prefix_len > 0:
         h = h[:, prefix_len:]  # loss only over text tokens
 
@@ -569,10 +640,7 @@ def prefill(
     h, caches, _ = forward_hidden(
         cfg, params, h, positions=positions, caches=caches,
         enc_out=enc_out, prefix_len=prefix_len, causal=True)
-    h = layers.rmsnorm(params["final_norm"], h)
-    logits = h[:, -1:].astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
-    logits = layers.softcap(logits, cfg.final_softcap)
-    return logits, caches
+    return head_logits(cfg, params, h[:, -1:]), caches
 
 
 def _add_decode_positions(
@@ -605,15 +673,13 @@ def decode_step(
     h = _add_decode_positions(cfg, h, positions)
     h, caches, _ = forward_hidden(
         cfg, params, h, positions=positions, caches=caches, cur_len=cur_len)
-    h = layers.rmsnorm(params["final_norm"], h)
-    logits = h.astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
-    logits = layers.softcap(logits, cfg.final_softcap)
-    return logits, caches
+    return head_logits(cfg, params, h), caches
 
 
 def decode_step_paged(
     cfg: ModelConfig, params: Params, tokens: jax.Array, caches: Params,
     page_table: jax.Array, cur_len: jax.Array, *, paged_kernel: bool = False,
+    count_pairs: bool = False,
 ) -> tuple[jax.Array, Params]:
     """One decode step against the paged KV pool (see ``init_paged_cache``).
 
@@ -621,17 +687,20 @@ def decode_step_paged(
     physical pool block; ``cur_len`` must be a (B,) vector (each slot sits at
     its own position).  Rows beyond each slot's ``cur_len`` are masked exactly
     as in :func:`decode_step`, so greedy outputs are token-identical to the
-    contiguous path.
+    contiguous path.  ``count_pairs`` adds a third output: the (B,) count
+    of each row's routed expert choices that the layers hold (see
+    :func:`forward_hidden`).
     """
     assert jnp.ndim(cur_len) == 1, "paged decode needs per-slot positions"
     h = meshutil.shard_batch(_embed_tokens(cfg, params, tokens))
     h = _add_decode_positions(cfg, h, cur_len[:, None])
-    h, caches, _ = forward_hidden(
+    h, caches, _, *pairs = forward_hidden(
         cfg, params, h, positions=cur_len[:, None], caches=caches,
-        cur_len=cur_len, page_table=page_table, paged_kernel=paged_kernel)
-    h = layers.rmsnorm(params["final_norm"], h)
-    logits = h.astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
-    logits = layers.softcap(logits, cfg.final_softcap)
+        cur_len=cur_len, page_table=page_table, paged_kernel=paged_kernel,
+        count_pairs=count_pairs)
+    logits = head_logits(cfg, params, h)
+    if count_pairs:
+        return logits, caches, pairs[0][:, 0]
     return logits, caches
 
 
@@ -678,10 +747,7 @@ def decode_step_multi(
     h = _add_decode_positions(cfg, h, positions)
     h, caches, _ = forward_hidden(
         cfg, params, h, positions=positions, caches=caches, cur_len=cur_len)
-    h = layers.rmsnorm(params["final_norm"], h)
-    logits = h.astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
-    logits = layers.softcap(logits, cfg.final_softcap)
-    return logits, caches
+    return head_logits(cfg, params, h), caches
 
 
 def decode_step_multi_paged(
@@ -701,10 +767,7 @@ def decode_step_multi_paged(
     h, caches, _ = forward_hidden(
         cfg, params, h, positions=positions, caches=caches, cur_len=cur_len,
         page_table=page_table, paged_kernel=paged_kernel)
-    h = layers.rmsnorm(params["final_norm"], h)
-    logits = h.astype(jnp.float32) @ _unembed(cfg, params).astype(jnp.float32).T
-    logits = layers.softcap(logits, cfg.final_softcap)
-    return logits, caches
+    return head_logits(cfg, params, h), caches
 
 
 def sample_tokens(
@@ -747,10 +810,12 @@ def decode_and_sample_paged(
     cfg: ModelConfig, params: Params, tokens: jax.Array, caches: Params,
     page_table: jax.Array, cur_len: jax.Array, *, temperature: float = 0.0,
     key: jax.Array | None = None, paged_kernel: bool = False,
+    count_pairs: bool = False,
 ) -> tuple[jax.Array, Params]:
-    """Paged twin of :func:`decode_and_sample`."""
-    logits, caches = decode_step_paged(
+    """Paged twin of :func:`decode_and_sample` (``count_pairs``: see
+    :func:`decode_step_paged`)."""
+    logits, caches, *pairs = decode_step_paged(
         cfg, params, tokens, caches, page_table, cur_len,
-        paged_kernel=paged_kernel)
-    return sample_tokens(
-        logits[:, -1], temperature=temperature, key=key), caches
+        paged_kernel=paged_kernel, count_pairs=count_pairs)
+    toks = sample_tokens(logits[:, -1], temperature=temperature, key=key)
+    return (toks, caches, *pairs)

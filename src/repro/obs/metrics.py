@@ -23,7 +23,7 @@ Design constraints (this sits on the tick path):
 from __future__ import annotations
 
 import math
-from typing import Any, Iterable
+from typing import Any, Callable, Iterable
 
 __all__ = ["Histogram", "MetricsRegistry", "SCHEMA_VERSION"]
 
@@ -120,6 +120,7 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._values: dict[str, Any] = {}
         self._hists: dict[str, Histogram] = {}
+        self._sources: list[Callable[[], dict[str, Any]]] = []
 
     # -- scalars ---------------------------------------------------------
 
@@ -148,6 +149,14 @@ class MetricsRegistry:
     def observe(self, name: str, v: float) -> None:
         self.histogram(name).observe(v)
 
+    # -- sources ---------------------------------------------------------
+
+    def add_source(self, read: Callable[[], dict[str, Any]]) -> None:
+        """Scalars kept elsewhere (counters accumulated on the device):
+        ``read()`` is polled by every ``snapshot`` and its values set
+        under their names. Nothing reads them on the tick path."""
+        self._sources.append(read)
+
     # -- snapshot --------------------------------------------------------
 
     def names(self) -> Iterable[str]:
@@ -155,6 +164,8 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict[str, Any]:
         """The machine-readable registry state (JSON-serializable)."""
+        for read in self._sources:
+            self._values.update(read())
         return {
             "schema": SCHEMA_VERSION,
             "counters": dict(sorted(self._values.items())),

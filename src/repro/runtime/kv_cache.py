@@ -65,6 +65,9 @@ from repro.models.transformer import ModelConfig
 PREFIX_STORE_SCHEMA = 1
 
 TRASH_PAGE = 0  # physical block 0: sink for padding writes, never allocated
+#: Pool leaves shared by every slot through the page table; any other leaf
+#: (SSM and conv state, cross-attention K/V) has one row per slot on axis 1.
+PAGED_LEAVES = ("k", "v", "k_scale", "v_scale")
 
 # Per-shape jitted scatter/gather/load helpers are cached by page count; an
 # unbounded dict would grow one compile per distinct prefix/evict size over a
@@ -586,6 +589,18 @@ class PagedKVCache:
                 if key in c:
                     leaf = c[key]
                     total += leaf.size * leaf.dtype.itemsize // self.num_blocks
+        return total
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """HBM bytes of one slot's per-slot state across all layers (SSM
+        and conv rows, cross-attention K/V): what travels with a slot's
+        pages on evict, readmit and the admission scatter."""
+        total = 0
+        for c in self.pools["blocks"].values():
+            for key, leaf in c.items():
+                if key not in PAGED_LEAVES:
+                    total += leaf.size * leaf.dtype.itemsize // self.max_batch
         return total
 
     def stats(self, *, active_slots: int = 0) -> PoolStats:

@@ -118,6 +118,35 @@ class ServableModel:
         self.single = serving.ServingEngine(cfg, params, scfg)
         #: StateStore when the arch supports recurrent-state snapshots
         self.snapshots: StateStore | None = None
+        #: Expert layers: (2,) int32 on the device, accumulated by the
+        #: paged decode program itself: the token-expert pairs it computed
+        #: on held experts, and all pairs routed, over its live rows.
+        self.device_counters: jax.Array | None = None
+        if any(spec.ffn == "moe" for spec in cfg.layer_unit):
+            self.reset_device_counters()
+
+    # -- counters kept on the device -------------------------------------------
+
+    def reset_device_counters(self) -> None:
+        self.device_counters = jnp.zeros((2,), jnp.int32)
+
+    def read_device_counters(self) -> dict[str, int]:
+        """The device counters by name (one small fetch; read at metric
+        snapshots, never on the tick path)."""
+        here, routed = np.asarray(jax.device_get(self.device_counters))
+        return {"moe.decode_pairs_here": int(here),
+                "moe.decode_pairs": int(routed)}
+
+    def _counting(self, step):
+        """``step`` takes and returns the device counters after the usual
+        arguments and outputs; the tick calls it without them, and the
+        counters stay on the device."""
+        def decode(*args):
+            toks, caches, self.device_counters = step(
+                *args, self.device_counters)
+            return toks, caches
+
+        return decode
 
     # -- per-slot state layout -------------------------------------------------
 
@@ -189,6 +218,29 @@ class ServableModel:
         def _keys(uids, steps):
             return jax.vmap(serving.slot_key)(uids, steps)
 
+        if paged and self.device_counters is not None:
+            kern = scfg.paged_kernel
+            per_row = cfg.top_k * cfg.n_repeats * sum(
+                spec.ffn == "moe" for spec in cfg.layer_unit)
+
+            def tally(out, cur, acc):
+                toks, caches, here = out
+                live = cur > 0  # free slots decode at position 0
+                return toks, caches, acc + jnp.stack([
+                    jnp.sum(jnp.where(live, here, 0)),
+                    jnp.sum(live.astype(jnp.int32)) * per_row])
+
+            if temp > 0.0:
+                return self._counting(jax.jit(
+                    lambda p, t, c, pt, l, u, s, a: tally(
+                        T.decode_and_sample_paged(
+                            cfg, p, t, c, pt, l, temperature=temp,
+                            key=_keys(u, s), paged_kernel=kern,
+                            count_pairs=True), l, a)))
+            return self._counting(jax.jit(
+                lambda p, t, c, pt, l, a: tally(T.decode_and_sample_paged(
+                    cfg, p, t, c, pt, l, paged_kernel=kern,
+                    count_pairs=True), l, a)))
         if paged:
             kern = scfg.paged_kernel
             if temp > 0.0:
@@ -249,11 +301,13 @@ class TransformerServable(ServableModel):
 
 
 class MambaServable(ServableModel):
-    """SSM (mamba2) and hybrid (jamba) configs.
+    """SSM (mamba2) and hybrid (jamba, granite) configs.
 
     Per-slot state is O(1) recurrent (SSM state + conv tail), carried by
     the cache/pool scatter-gather as opaque per-slot leaves — eviction,
-    readmission and preemption work unchanged.  Page-granular prefix
+    readmission and preemption work unchanged. With fused prefill (the
+    paged default) each chunk reads and writes the admitted slot's state
+    rows in the pool directly.  Page-granular prefix
     sharing is impossible (the state at position ``t`` summarizes *all*
     of ``[0, t)``), so sharing degrades to **state snapshots**: admission
     restores the longest stored chunk-aligned proper prefix of the prompt

@@ -121,7 +121,7 @@ from repro.obs import MetricsRegistry, Tracer
 from repro.kernels import quant
 from repro.models import transformer as T
 from repro.models.transformer import ModelConfig
-from repro.runtime.kv_cache import PagedKVCache, _lru_jit
+from repro.runtime.kv_cache import PAGED_LEAVES, PagedKVCache, _lru_jit
 
 
 @dataclasses.dataclass
@@ -146,8 +146,10 @@ class ServeConfig:
     # greedy-token divergence (parity becomes tolerance-based, not bitwise)
     fused_prefill: bool | None = None  # write prefill K/V projections
     # straight into pool blocks through the page table (no contiguous slab
-    # + second jitted scatter); None = on for paged transformer archs,
-    # off elsewhere — resolved by validate_arch once arch_kind is stamped
+    # + second jitted scatter), and a recurrent slot's state straight into
+    # its pool rows; None = on for paged transformer and mamba/hybrid archs
+    # (not with state_snapshots), off elsewhere — resolved by validate_arch
+    # once arch_kind is stamped
     prefix_sharing: bool = False  # map common prompt prefixes COW (SYNC once)
     prefix_min_pages: int = 1  # shortest prefix worth sharing, in pages
     # speculative multi-token decode (repro.runtime.spec): a drafter
@@ -248,6 +250,9 @@ class ServeConfig:
                 f"unknown arch_kind {kind!r}; expected "
                 "transformer | mamba | whisper")
         if kind == "mamba":
+            # Served: paged pools with fused prefill (the slot's SSM and
+            # conv rows written in place). Refused: prefix sharing,
+            # speculative decode and quantized pools.
             if self.prefix_sharing:
                 raise NotImplementedError(
                     "prefix sharing maps attention KV pages; mamba/hybrid "
@@ -259,6 +264,11 @@ class ServeConfig:
                     "speculative decode rolls rejected positions back by "
                     "masking KV writes; mamba/hybrid archs advance "
                     "irreversible per-slot SSM state")
+            if self.fused_prefill and self.state_snapshots:
+                raise NotImplementedError(
+                    "state_snapshots restore a b=1 prefill cache; fused "
+                    "prefill keeps the slot's state in the pool — use one "
+                    "or the other")
         if kind == "whisper":
             if self.prefix_sharing:
                 raise NotImplementedError(
@@ -278,16 +288,19 @@ class ServeConfig:
                     f"arch_kind={kind!r} carries cache state (SSM rows / "
                     "cross-attention slabs) with no per-page scale — serve "
                     "it with kv_dtype='fp32'")
-            if self.fused_prefill:
-                raise NotImplementedError(
-                    "fused_prefill routes prefill K/V through the decoder "
-                    f"page table; arch_kind={kind!r} prefills through "
-                    "arch-specific caches — leave fused_prefill unset")
+        if kind == "whisper" and self.fused_prefill:
+            raise NotImplementedError(
+                "fused_prefill routes prefill K/V through the decoder page "
+                "table; whisper prefills through its cross-attention "
+                "caches — leave fused_prefill unset")
         if self.fused_prefill is None:
             # Resolved here (not __post_init__) because the default depends
-            # on the architecture: the fused path exists only for the
-            # transformer prefill chain over a paged pool.
-            self.fused_prefill = bool(self.paged) and kind == "transformer"
+            # on the architecture: the fused path exists for the decoder
+            # prefill chain over a paged pool, attention K/V and recurrent
+            # state alike (not beside state snapshots).
+            self.fused_prefill = bool(self.paged) and (
+                kind == "transformer"
+                or (kind == "mamba" and not self.state_snapshots))
         if self.state_snapshots and kind != "mamba":
             raise ValueError(
                 "state_snapshots reuse recurrent SSM state across "
@@ -308,6 +321,35 @@ def slot_key(uid, step):
     verify); jit/vmap-traceable."""
     return jax.random.fold_in(
         jax.random.fold_in(jax.random.PRNGKey(0), uid), step)
+
+
+def _slot_rows(pools: Any, slot: jax.Array, *, fresh: bool) -> Any:
+    """The pools with each per-slot leaf cut to slot ``slot``'s (b=1) row,
+    zeros when ``fresh``; paged leaves pass through whole."""
+    def row(key, leaf):
+        if key in PAGED_LEAVES:
+            return leaf
+        if fresh:
+            return jnp.zeros(leaf.shape[:1] + (1,) + leaf.shape[2:],
+                             leaf.dtype)
+        return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=1)
+
+    return {"blocks": {name: {k: row(k, v) for k, v in c.items()}
+                       for name, c in pools["blocks"].items()}}
+
+
+def _put_slot_rows(pools: Any, rows: Any, slot: jax.Array) -> Any:
+    """Inverse of :func:`_slot_rows`: each per-slot row written back into
+    the pools at ``slot``; paged leaves are taken from ``rows`` whole."""
+    def put(key, leaf, new):
+        if key in PAGED_LEAVES:
+            return new
+        return jax.lax.dynamic_update_slice_in_dim(
+            leaf, new.astype(leaf.dtype), slot, axis=1)
+
+    return {"blocks": {
+        name: {k: put(k, v, rows["blocks"][name][k]) for k, v in c.items()}
+        for name, c in pools["blocks"].items()}}
 
 
 class ServingEngine:
@@ -365,12 +407,7 @@ class ServingEngine:
                     enc_out=enc_out,
                     prefix_len=cfg.prefix_len if has_prefix else 0,
                     causal=True, q_offset=pos0)
-                from repro.models import layers
-                h = layers.rmsnorm(params["final_norm"], h)
-                logits = h[:, -1:].astype(jnp.float32) @ T._unembed(
-                    cfg, params).astype(jnp.float32).T
-                logits = layers.softcap(logits, cfg.final_softcap)
-                return logits, caches
+                return T.head_logits(cfg, params, h[:, -1:]), caches
 
             return jax.jit(fn)
 
@@ -386,16 +423,18 @@ class ServingEngine:
         decomposition the contiguous path uses, so the pool contents are
         bitwise-identical to scatter-after-attention at fp32.
 
-        Transformer-only (no prefix embeds / encoder output): the engine
-        gates on ``ServeConfig.fused_prefill``, which ``validate_arch``
-        resolves to False for every other arch.
+        A recurrent layer's per-slot state (SSM and conv rows, slot-indexed
+        in the pool) is read from row ``slot`` (zeros on the first chunk)
+        and written back there. Decoder archs only (no prefix embeds /
+        encoder output): the engine gates on ``ServeConfig.fused_prefill``,
+        which ``validate_arch`` refuses for whisper.
         """
         key = ("fused", chunk_len, pos0)
 
         def make():
             cfg = self.cfg
 
-            def fn(params, pools, page_table, tokens):
+            def fn(params, pools, page_table, tokens, slot):
                 h = T._embed_tokens(cfg, params, tokens)
                 s = h.shape[1]
                 if cfg.sinusoidal_pos:
@@ -403,15 +442,12 @@ class ServingEngine:
                     h = h + _l.sinusoidal_positions(
                         pos0 + s, cfg.d_model, cfg.compute_dtype)[None, pos0:]
                 positions = pos0 + jnp.arange(s)
-                h, pools, _ = T.forward_hidden(
-                    cfg, params, h, positions=positions, caches=pools,
+                caches = _slot_rows(pools, slot, fresh=pos0 == 0)
+                h, caches, _ = T.forward_hidden(
+                    cfg, params, h, positions=positions, caches=caches,
                     causal=True, q_offset=pos0, page_table=page_table)
-                from repro.models import layers
-                h = layers.rmsnorm(params["final_norm"], h)
-                logits = h[:, -1:].astype(jnp.float32) @ T._unembed(
-                    cfg, params).astype(jnp.float32).T
-                logits = layers.softcap(logits, cfg.final_softcap)
-                return logits, pools
+                pools = _put_slot_rows(pools, caches, slot)
+                return T.head_logits(cfg, params, h[:, -1:]), pools
 
             return jax.jit(fn)
 
@@ -768,6 +804,7 @@ class StreamedBatchEngine:
         self._tick_index = 0  # span ordinal (tick= arg on decode spans)
         self._budget_flagged = False  # live-STR002 warned once per engine
         self.servable = build_servable(cfg, params, scfg)
+        self.metrics = self.metrics  # attach the servable's device counters
         self.single = self.servable.single  # b=1 prefill machinery
         b = scfg.max_batch
         self.paged = scfg.paged
@@ -850,6 +887,20 @@ class StreamedBatchEngine:
         self._decode_budget = budget_of(self.servable.decode_fn)
         self._verify_budget = (budget_of(self.servable.make_verifier)
                                if scfg.spec_decode else None)
+
+    @property
+    def metrics(self) -> MetricsRegistry:
+        return self._metrics
+
+    @metrics.setter
+    def metrics(self, registry: MetricsRegistry) -> None:
+        """Install ``registry``; the servable's counters kept on the device
+        restart from zero and are read into it at each snapshot."""
+        self._metrics = registry
+        servable = getattr(self, "servable", None)
+        if servable is not None and servable.device_counters is not None:
+            servable.reset_device_counters()
+            registry.add_source(servable.read_device_counters)
 
     # -- queue ----------------------------------------------------------------
 
@@ -1019,6 +1070,7 @@ class StreamedBatchEngine:
             row = np.full((1, self.kv.max_pages), 0, np.int32)
             own = self.kv.slot_pages(slot.index)
             row[0, : len(own)] = own
+            slot_index = jnp.int32(slot.index)
         self.obs.add("host", "stage", st0, uid=req.uid)
         if use_fused:
             s_total = tokens.shape[1]
@@ -1036,7 +1088,7 @@ class StreamedBatchEngine:
                 self.obs.add("host", "stage", st0, uid=req.uid)
                 dt0 = self.obs.begin("dispatch")
                 logits, self.kv.pools = fn(
-                    self.params, self.kv.pools, table, piece)
+                    self.params, self.kv.pools, table, piece, slot_index)
                 self.obs.add("host", "dispatch", dt0, uid=req.uid)
                 pos += piece.shape[1]
                 n_chunks += 1
@@ -1084,7 +1136,8 @@ class StreamedBatchEngine:
                     slot.index, caches, pos, start_page=shared_pages)
                 self.obs.add("transfer", "page_scatter", pt0, uid=req.uid,
                              pages=int(self.kv.pages_for(pos)
-                                       - shared_pages))
+                                       - shared_pages),
+                             state_bytes=self.kv.slot_state_bytes)
             self.kv.publish(slot.index)
             if self.scfg.prefix_sharing:
                 self.kv.register_prefix(
@@ -1624,7 +1677,8 @@ class StreamedBatchEngine:
             caches = self._gather_jit(self.caches, jnp.int32(slot.index))
             n_pages = 0
         self.obs.add("transfer", "evict", et0, uid=uid, pages=n_pages,
-                     cur=slot.cur, slot=slot.index)
+                     cur=slot.cur, slot=slot.index,
+                     state_bytes=self.kv.slot_state_bytes if self.paged else 0)
         ev = EvictedRequest(
             uid=uid, caches=caches,
             cur=slot.cur, pending=slot.pending,
@@ -1692,7 +1746,8 @@ class StreamedBatchEngine:
                 self.caches, ev.caches, jnp.int32(slot.index))
         self.obs.add("transfer", "readmit", rt0, uid=ev.uid,
                      pages=ev.n_pages, shared_pages=shared_pages,
-                     slot=slot.index)
+                     slot=slot.index,
+                     state_bytes=self.kv.slot_state_bytes if self.paged else 0)
         slot.uid = ev.uid
         slot.cur = ev.cur
         slot.pending = ev.pending

@@ -74,6 +74,7 @@ class TestFullConfigs:
             "mamba2-2.7b": 2.7e9,
             "paligemma-3b": 2.5e9,  # text backbone (vision tower stubbed)
             "jamba-1.5-large-398b": 398e9,
+            "granite-4.0-h-small": 32.2e9,
         }
         for arch, want in expected.items():
             got = C.get_config(arch).param_count()
@@ -99,10 +100,11 @@ class TestFullConfigs:
     def test_cells_accounting(self):
         cells = C.cells()
         skipped = [c for c in C.cells(include_skipped=True) if c[1].endswith(":SKIP")]
-        assert len(cells) + len(skipped) == 40
+        assert len(cells) + len(skipped) == 4 * len(C.list_archs()) == 44
         assert len(skipped) == 7
         long_archs = {a for a, s in cells if s == "long_500k"}
-        assert long_archs == {"mamba2-2.7b", "mixtral-8x7b", "jamba-1.5-large-398b"}
+        assert long_archs == {"mamba2-2.7b", "mixtral-8x7b",
+                              "jamba-1.5-large-398b", "granite-4.0-h-small"}
 
 
 class TestChunkingInvariance:

@@ -36,6 +36,7 @@ EXPECTED_KIND = {
     "qwen2-moe-a2.7b": "transformer",
     "mamba2-2.7b": "mamba",
     "jamba-1.5-large-398b": "mamba",
+    "granite-4.0-h-small": "mamba",
     "whisper-medium": "whisper",
     "paligemma-3b": "prefix_lm",
 }
