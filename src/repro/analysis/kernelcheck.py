@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import functools
 import inspect
-import math
 
 import jax
 import jax.numpy as jnp
@@ -224,18 +223,19 @@ def check_quant_contract() -> list[Finding]:
                 "KRN003", f"quant.scales_of[{kind}]",
                 f"per-page scale is {sc.shape} {sc.dtype}, kernel expects "
                 "(kv_heads,) float32 per page", "kernel"))
-    # The scale operand the kernel's BlockSpec tiles must be a free view
-    # of the (num_blocks, kv_heads) scale pool: same page axis, kv heads
-    # last, nothing else but unit dims.
+    # The scale operand the kernel's BlockSpec tiles must be the layers'
+    # stack of (num_blocks, kv_heads) scale pools: the layer axis first,
+    # then the page axis, kv heads last.
+    layers = 3
     sp = paged_attention.build_specs(b, hkv, h // hkv, hd, nb, bs, 4,
-                                     quantized=True)
+                                     quantized=True, layers=layers)
     for i in (3, 4):
         view = tuple(sp["operands"][i])
-        if (view[0], view[-1]) != (nb, hkv) or math.prod(view) != nb * hkv:
+        if view != (layers, nb, hkv):
             findings.append(Finding(
                 "KRN003", f"paged_attention:in[{i}]",
-                f"kernel tiles scales as {view}, not a view of the "
-                f"({nb}, {hkv}) per-page scale pool", "kernel"))
+                f"kernel tiles scales as {view}, not the ({layers}, {nb}, "
+                f"{hkv}) stack of per-page scale pools", "kernel"))
     return findings
 
 

@@ -54,7 +54,7 @@ PROTECTED = {
         attrs={"_owned", "page_table", "pools"},
         methods={"__init__", "alloc", "map_shared", "shield", "publish",
                  "ensure_write", "truncate", "release", "scatter",
-                 "load_prefixes", "_copy_block"},
+                 "load_prefixes", "_copy_block", "adopt"},
     ),
 }
 

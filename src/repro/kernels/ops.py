@@ -81,16 +81,19 @@ def paged_attention(
     window=0,
     softcap=0.0,
     scale=None,
+    layer=None,  # int32 layer index: the pools are stacked over layers
     interpret=None,
 ):
     """Decode attention directly from the paged KV pool: the page table is
     scalar-prefetched and the kernel copies each row's live pages into VMEM
-    itself, instead of materializing a contiguous copy in HBM."""
+    itself, instead of materializing a contiguous copy in HBM. With
+    ``layer`` the pools are the model's ``(layers, num_blocks, ...)``
+    stacks and the kernel's copies read that layer's pages in place."""
     interpret = _default_interpret() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     return _pa.paged_attention_kernel(
         q, k_pool, v_pool, page_table, cur_len, window=window,
-        softcap=softcap, scale=scale, interpret=interpret)
+        softcap=softcap, scale=scale, interpret=interpret, layer=layer)
 
 
 @functools.partial(
@@ -105,6 +108,7 @@ def paged_attention_multi(
     window=0,
     softcap=0.0,
     scale=None,
+    layer=None,  # int32 layer index into stacked pools
     interpret=None,
 ):
     """q_len>1 paged decode (speculative verify): scores a pending token
@@ -114,7 +118,7 @@ def paged_attention_multi(
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     return _pa.paged_attention_multi_kernel(
         q, k_pool, v_pool, page_table, cur_len, window=window,
-        softcap=softcap, scale=scale, interpret=interpret)
+        softcap=softcap, scale=scale, interpret=interpret, layer=layer)
 
 
 @functools.partial(
@@ -131,6 +135,7 @@ def paged_attention_quant(
     window=0,
     softcap=0.0,
     scale=None,
+    layer=None,  # int32 layer index into stacked pools and scales
     interpret=None,
 ):
     """Quantized-pool decode attention with dequantization fused into the
@@ -142,7 +147,7 @@ def paged_attention_quant(
     return _pa.paged_attention_kernel(
         q, k_pool, v_pool, page_table, cur_len, window=window,
         softcap=softcap, scale=scale, interpret=interpret,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, layer=layer)
 
 
 @functools.partial(
@@ -159,6 +164,7 @@ def paged_attention_multi_quant(
     window=0,
     softcap=0.0,
     scale=None,
+    layer=None,  # int32 layer index into stacked pools and scales
     interpret=None,
 ):
     """Quantized q_len>1 paged decode (speculative verify) with fused
@@ -168,7 +174,7 @@ def paged_attention_multi_quant(
     return _pa.paged_attention_multi_kernel(
         q, k_pool, v_pool, page_table, cur_len, window=window,
         softcap=softcap, scale=scale, interpret=interpret,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, layer=layer)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "row_tile", "interpret"))

@@ -10,21 +10,25 @@ order. A compute block is P logical pages of one row, P * block_size keys.
 P follows from the shapes (:func:`pages_per_block`: ``KEYS_PER_BLOCK``
 keys, clamped to [1, n_pages]), so the last block may be partial.
 
-DMA: the K and V pools stay in HBM (``memory_space=pl.ANY``); each block's
-pages are copied, one async copy per page by the physical id in the table,
-into one half of a double buffer ``(2, P, block_size, Hkv, hd)``. Only live
-pages are copied: a page is live for a row when it holds a position at or
-below the row's youngest query (``cur_len + q_len - 1``), inside the oldest
-query's window when ``window > 0``, and lies inside the table. A row's live
-pages are one run, so a block's are a range of its slots, and a row's live
-blocks are consecutive. Each live step starts the copies of the next live
-block in row order into the other half (the same row's next block, or after
-the row's last live block the next row's first), then waits for its own and
-computes. A block with no live page issues no copy and no compute, so a
-dead tail costs only empty grid steps. The V slots of a live block that
-were not copied are zeroed, so stale VMEM never meets a zero softmax weight.
-A quantized pool's (num_blocks, Hkv) f32 scales sit whole in VMEM; a page's
-row of scales is copied beside the page, by the same physical id.
+DMA: the K and V pools stay in HBM (``memory_space=pl.ANY``). They are the
+model's stacked pools ``(layers, num_blocks, block_size, Hkv, hd)``, and a
+scalar-prefetched layer index picks the layer, so a decode step hands every
+attention layer the same buffers and no layer's pool is ever sliced out.
+Each block's pages are copied, one async copy per page at ``[layer,
+physical id]``, into one half of a double buffer ``(2, P, block_size, Hkv,
+hd)``. Only live pages are copied: a page is live for a row when it holds a
+position at or below the row's youngest query (``cur_len + q_len - 1``),
+inside the oldest query's window when ``window > 0``, and lies inside the
+table. A row's live pages are one run, so a block's are a range of its
+slots, and a row's live blocks are consecutive. Each live step starts the
+copies of the next live block in row order into the other half (the same
+row's next block, or after the row's last live block the next row's first),
+then waits for its own and computes. A block with no live page issues no
+copy and no compute, so a dead tail costs only empty grid steps. The V
+slots of a live block that were not copied are zeroed, so stale VMEM never
+meets a zero softmax weight. A quantized pool's (num_blocks, Hkv) f32
+scales of the layer sit whole in VMEM; a page's row of scales is copied
+beside the page, by the same physical id.
 
 Compute: all KV heads at once. The queries of every head, ``Hkv * q_len *
 g`` rows, are scored against the whole block viewed as ``(P * block_size *
@@ -74,13 +78,14 @@ def _iota(shape, dim):
 def _paged_kernel(
     pt_ref,  # SMEM (B, n_pages) int32: scalar-prefetched page table
     cl_ref,  # SMEM (B,) int32: per-row current position
+    ly_ref,  # SMEM (1,) int32: the layer of the stacked pools
     q_ref,  # (1, Hkv * q_len * g, hd): head-major rows
-    k_hbm,  # (num_blocks, bs, Hkv, hd) pool, left in HBM
+    k_hbm,  # (layers, num_blocks, bs, Hkv, hd) pools, left in HBM
     v_hbm,
     *rest,  # quantized: (ks_ref, vs_ref, o_ref, m, l, acc, kbuf, vbuf,
-    # ksbuf, vsbuf, par, sem), the scale pools (num_blocks, Hkv) whole in
-    # VMEM and their per-page rows (2, P, 1, Hkv); else (o_ref, m, l, acc,
-    # kbuf, vbuf, par, sem)
+    # ksbuf, vsbuf, par, sem), the layer's scale pools (num_blocks, Hkv)
+    # whole in VMEM and their per-page rows (2, P, 1, Hkv); else (o_ref, m,
+    # l, acc, kbuf, vbuf, par, sem)
     n_pages: int,
     pages: int,
     q_len: int,
@@ -117,8 +122,8 @@ def _paged_kernel(
 
     def copies(row, blk, slot, i):
         pid = pt_ref[row, blk * pages + i]
-        return pid, [pltpu.make_async_copy(src.at[pid], dst.at[slot, i],
-                                           sem.at[slot])
+        return pid, [pltpu.make_async_copy(src.at[ly_ref[0], pid],
+                                           dst.at[slot, i], sem.at[slot])
                      for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))]
 
     def fetch(row, blk, first, end, slot):
@@ -244,26 +249,29 @@ def _col_scales(sc: jax.Array, bs: int) -> jax.Array:
 
 
 def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
-                n_pages: int, *, quantized: bool,
+                n_pages: int, *, quantized: bool, layers: int = 1,
                 pool_dtype=jnp.bfloat16) -> dict:
     """Grid/BlockSpec layout shared by the kernel call *and* the analyzer's
     kernel lint (``analysis.kernelcheck``). ``rows`` is the query rows per
-    KV head (``q_len * g``).
+    KV head (``q_len * g``); ``layers`` is the stacked pools' leading axis.
 
-    The page table and ``cur_len`` are the two scalar-prefetch operands.
-    The queries and the output are blocked per batch row. The pools are
-    HBM-resident (``pl.ANY``; ``hbm_operands`` names them and the rank the
-    body's copies index), so no index_map reads the page table: the body's
-    DMAs do, which ``KERNEL_META``'s ``dma_index_operands`` declares.
-    ``cur_len`` is body-consumed (position masking). ``operands`` /
-    ``out_shape`` are the wrapper-declared shapes (same order as
-    ``in_specs``, prefetch excluded).
+    The page table, ``cur_len`` and the layer index are the three
+    scalar-prefetch operands. The queries and the output are blocked per
+    batch row. The pools are HBM-resident (``pl.ANY``; ``hbm_operands``
+    names them and the rank the body's copies index, ``[layer, page]``),
+    so no index_map reads the page table: the body's DMAs do, which
+    ``KERNEL_META``'s ``dma_index_operands`` declares. ``cur_len`` is
+    body-consumed (position masking). ``operands`` / ``out_shape`` are the
+    wrapper-declared shapes (same order as ``in_specs``, prefetch
+    excluded).
     """
     pages = pages_per_block(bs, n_pages)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    qo = pl.BlockSpec((1, hkv * rows, hd), lambda bb, jj, pt, cl: (bb, 0, 0))
+    qo = pl.BlockSpec((1, hkv * rows, hd),
+                      lambda bb, jj, pt, cl, ly: (bb, 0, 0))
     in_specs = [qo, hbm, hbm]
-    operands = [(b, hkv * rows, hd), (nb, bs, hkv, hd), (nb, bs, hkv, hd)]
+    pool = (layers, nb, bs, hkv, hd)
+    operands = [(b, hkv * rows, hd), pool, pool]
     scratch = [
         pltpu.VMEM((hkv * rows, 1), jnp.float32),
         pltpu.VMEM((hkv * rows, 1), jnp.float32),
@@ -272,19 +280,21 @@ def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
         pltpu.VMEM((2, pages, bs, hkv, hd), pool_dtype),
     ]
     if quantized:
-        # The (num_blocks, Hkv) scale pools are small (4 B a page and
-        # head): one whole block each, fetched once per call.
-        in_specs += [pl.BlockSpec((nb, hkv), lambda bb, jj, pt, cl: (0, 0))
+        # The layer's (num_blocks, Hkv) scale pools are small (4 B a page
+        # and head): one whole block each, fetched once per call.
+        in_specs += [pl.BlockSpec((None, nb, hkv),
+                                  lambda bb, jj, pt, cl, ly: (ly[0], 0, 0))
                      ] * 2
-        operands += [(nb, hkv), (nb, hkv)]
+        operands += [(layers, nb, hkv), (layers, nb, hkv)]
         scratch += [pltpu.VMEM((2, pages, 1, hkv), jnp.float32)] * 2
     return dict(
         grid=(b, pl.cdiv(n_pages, pages)),
         pages=pages,
-        num_scalar_prefetch=2,
-        prefetch_index_operands=(0,),  # page table; cur_len is body-read
+        num_scalar_prefetch=3,
+        # The page table and the layer; cur_len is body-read.
+        prefetch_index_operands=(0, 2),
         in_specs=in_specs,
-        hbm_operands={1: 4, 2: 4},
+        hbm_operands={1: 5, 2: 5},
         out_specs=qo,
         scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32),
                                   pltpu.SemaphoreType.DMA((2,))],
@@ -296,26 +306,40 @@ def build_specs(b: int, hkv: int, rows: int, hd: int, nb: int, bs: int,
 #: Analyzer metadata: lint-time instantiations of ``build_specs`` covering
 #: the plain, multi-token (rows = q_len * g) and quantized variants.
 _META = dict(build=build_specs, grid_dims=("batch", "page_blocks"),
-             dma_index_operands=(0,))
+             dma_index_operands=(0, 2))
 KERNEL_META = {
     "paged_attention": dict(
         _META, lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8,
-                                n_pages=4, quantized=False)),
+                                n_pages=4, quantized=False, layers=3)),
     "paged_attention_multi": dict(
         _META, lint_shapes=dict(b=2, hkv=2, rows=12, hd=8, nb=9, bs=8,
-                                n_pages=4, quantized=False)),
+                                n_pages=4, quantized=False, layers=3)),
     "paged_attention_quant": dict(
         _META, lint_shapes=dict(b=2, hkv=2, rows=4, hd=8, nb=9, bs=8,
-                                n_pages=4, quantized=True)),
+                                n_pages=4, quantized=True, layers=3)),
 }
+
+
+def _stacked(k_pool, v_pool, k_scale, v_scale, layer):
+    """The pools as ``(layers, ...)`` stacks and the layer index as the
+    (1,) int32 prefetch operand; one layer's pools (no ``layer``) are a
+    stack of one, a free reshape."""
+    if layer is None:
+        return (k_pool[None], v_pool[None],
+                None if k_scale is None else k_scale[None],
+                None if v_scale is None else v_scale[None],
+                jnp.zeros((1,), jnp.int32))
+    return (k_pool, v_pool, k_scale, v_scale,
+            jnp.asarray(layer, jnp.int32).reshape(1))
 
 
 def _paged_call(
     qr: jax.Array,  # (B, Hkv, q_len * g, hd)
-    k_pool: jax.Array,
+    k_pool: jax.Array,  # (layers, num_blocks, bs, Hkv, hd)
     v_pool: jax.Array,
     page_table: jax.Array,
     cur_len: jax.Array,
+    layer: jax.Array,  # (1,) int32
     *,
     q_len: int,
     group: int,
@@ -323,22 +347,22 @@ def _paged_call(
     softcap: float,
     scale: float,
     interpret: bool,
-    k_scale: jax.Array | None = None,  # (num_blocks, Hkv) f32 per-page
-    v_scale: jax.Array | None = None,  # per-head scales (quantized pools)
+    k_scale: jax.Array | None = None,  # (layers, num_blocks, Hkv) f32
+    v_scale: jax.Array | None = None,  # per-page, per-head scales
 ) -> jax.Array:
     b, hkv, rows, hd = qr.shape
-    nb, bs, _, _ = k_pool.shape
+    n_layers, nb, bs, _, _ = k_pool.shape
     n_pages = page_table.shape[1]
     quantized = k_scale is not None
     sp = build_specs(b, hkv, rows, hd, nb, bs, n_pages, quantized=quantized,
-                     pool_dtype=k_pool.dtype)
+                     layers=n_layers, pool_dtype=k_pool.dtype)
     kern = functools.partial(
         _paged_kernel, n_pages=n_pages, pages=sp["pages"], q_len=q_len,
         group=group, window=window, softcap=softcap, scale=scale,
         quantized=quantized)
 
     inputs = [page_table.astype(jnp.int32), cur_len.astype(jnp.int32),
-              qr.reshape(b, hkv * rows, hd), k_pool, v_pool]
+              layer, qr.reshape(b, hkv * rows, hd), k_pool, v_pool]
     if quantized:
         inputs += [k_scale, v_scale]
 
@@ -366,8 +390,8 @@ def _paged_call(
 
 def paged_attention_kernel(
     q: jax.Array,  # (B, H, hd) single-token queries (H = Hkv * G)
-    k_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd)
-    v_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd), or stacked
+    v_pool: jax.Array,  # (layers, num_blocks, ...) with ``layer``
     page_table: jax.Array,  # (B, n_pages) int32
     cur_len: jax.Array,  # (B,) int32
     *,
@@ -375,17 +399,20 @@ def paged_attention_kernel(
     softcap: float = 0.0,
     scale: float,
     interpret: bool = False,
-    k_scale: jax.Array | None = None,  # (num_blocks, Hkv) f32: quantized
-    v_scale: jax.Array | None = None,  # pool scales (dequant fused in)
+    k_scale: jax.Array | None = None,  # ([layers,] num_blocks, Hkv) f32:
+    v_scale: jax.Array | None = None,  # quantized pool scales (fused)
+    layer: jax.Array | int | None = None,  # int32 index into stacked pools
 ) -> jax.Array:
     b, h, hd = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    hkv = k_pool.shape[-2]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     # Head layout matches _broadcast_kv: query head i attends kv head i // g.
     qr = q.reshape(b, hkv, g, hd)
+    k_pool, v_pool, k_scale, v_scale, ly = _stacked(
+        k_pool, v_pool, k_scale, v_scale, layer)
     out = _paged_call(
-        qr, k_pool, v_pool, page_table, cur_len, q_len=1, group=g,
+        qr, k_pool, v_pool, page_table, cur_len, ly, q_len=1, group=g,
         window=window, softcap=softcap, scale=scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale)
     return out.reshape(b, h, hd)
@@ -393,8 +420,8 @@ def paged_attention_kernel(
 
 def paged_attention_multi_kernel(
     q: jax.Array,  # (B, T, H, hd): T-token draft block per slot
-    k_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd)
-    v_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd), or stacked
+    v_pool: jax.Array,  # (layers, num_blocks, ...) with ``layer``
     page_table: jax.Array,  # (B, n_pages) int32
     cur_len: jax.Array,  # (B,) int32: position of token 0 per slot
     *,
@@ -402,22 +429,25 @@ def paged_attention_multi_kernel(
     softcap: float = 0.0,
     scale: float,
     interpret: bool = False,
-    k_scale: jax.Array | None = None,  # (num_blocks, Hkv) f32: quantized
-    v_scale: jax.Array | None = None,  # pool scales (dequant fused in)
+    k_scale: jax.Array | None = None,  # ([layers,] num_blocks, Hkv) f32:
+    v_scale: jax.Array | None = None,  # quantized pool scales (fused)
+    layer: jax.Array | int | None = None,  # int32 index into stacked pools
 ) -> jax.Array:
     """q_len>1 decode from the pool: query t of slot b sits at absolute
     position ``cur_len[b] + t`` (speculative verify: one pending token plus
     the draft tail), masked causally within the block."""
     b, t, h, hd = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    hkv = k_pool.shape[-2]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     # (B, T, Hkv, g, hd) -> (B, Hkv, T, g, hd): row r = query r // g of
     # group member r % g, matching the kernel's row -> position map.
     qr = q.reshape(b, t, hkv, g, hd).transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, t * g, hd)
+    k_pool, v_pool, k_scale, v_scale, ly = _stacked(
+        k_pool, v_pool, k_scale, v_scale, layer)
     out = _paged_call(
-        qr, k_pool, v_pool, page_table, cur_len, q_len=t, group=g,
+        qr, k_pool, v_pool, page_table, cur_len, ly, q_len=t, group=g,
         window=window, softcap=softcap, scale=scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale)
     return out.reshape(b, hkv, t, g, hd).transpose(0, 2, 1, 3, 4).reshape(

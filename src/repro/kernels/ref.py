@@ -41,6 +41,11 @@ def attention_ref(
     return out.astype(q.dtype)
 
 
+def _layer_of(layer, *pools):
+    """``pools`` as they are, or with ``layer`` each stack's that layer."""
+    return pools if layer is None else tuple(p[layer] for p in pools)
+
+
 def paged_attention_ref(
     q: jax.Array,  # (B, H, hd) single-token queries (H = Hkv * G)
     k_pool: jax.Array,  # (num_blocks, block_size, Hkv, hd)
@@ -51,10 +56,13 @@ def paged_attention_ref(
     window: int = 0,
     softcap: float = 0.0,
     scale: float,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Pure-jnp oracle for the paged decode-attention kernel: gather each
     row's pages into a contiguous logical view, then masked attention with
-    the per-row ``cur_len`` visibility cut."""
+    the per-row ``cur_len`` visibility cut. With ``layer`` the pools are
+    stacked ``(layers, num_blocks, ...)`` and that layer's are read."""
+    k_pool, v_pool = _layer_of(layer, k_pool, v_pool)
     b, h, hd = q.shape
     nb, bs, hkv, _ = k_pool.shape
     g = h // hkv
@@ -92,10 +100,13 @@ def paged_attention_multi_ref(
     window: int = 0,
     softcap: float = 0.0,
     scale: float,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Oracle for the q_len>1 paged decode kernel: gather each row's pages,
     then attention with the per-query causal cut — query t at absolute
-    position ``cur_len + t`` sees keys at positions ``<= cur_len + t``."""
+    position ``cur_len + t`` sees keys at positions ``<= cur_len + t``.
+    ``layer``: as in :func:`paged_attention_ref`."""
+    k_pool, v_pool = _layer_of(layer, k_pool, v_pool)
     b, t, h, hd = q.shape
     nb, bs, hkv, _ = k_pool.shape
     g = h // hkv
@@ -132,10 +143,13 @@ def _dequant_pool(pool: jax.Array, scale: jax.Array) -> jax.Array:
 def paged_attention_quant_ref(
     q, k_pool, v_pool, k_scale, v_scale, page_table, cur_len, *,
     window: int = 0, softcap: float = 0.0, scale: float,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Oracle for the fused-dequant paged kernel: dequantize the whole pool
     up front (exactly codes * scale, the value the kernel reconstructs
     per block), then the existing paged oracle."""
+    k_pool, v_pool, k_scale, v_scale = _layer_of(
+        layer, k_pool, v_pool, k_scale, v_scale)
     return paged_attention_ref(
         q, _dequant_pool(k_pool, k_scale), _dequant_pool(v_pool, v_scale),
         page_table, cur_len, window=window, softcap=softcap, scale=scale)
@@ -144,8 +158,11 @@ def paged_attention_quant_ref(
 def paged_attention_multi_quant_ref(
     q, k_pool, v_pool, k_scale, v_scale, page_table, cur_len, *,
     window: int = 0, softcap: float = 0.0, scale: float,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """q_len>1 twin of :func:`paged_attention_quant_ref`."""
+    k_pool, v_pool, k_scale, v_scale = _layer_of(
+        layer, k_pool, v_pool, k_scale, v_scale)
     return paged_attention_multi_ref(
         q, _dequant_pool(k_pool, k_scale), _dequant_pool(v_pool, v_scale),
         page_table, cur_len, window=window, softcap=softcap, scale=scale)

@@ -367,6 +367,7 @@ def paged_decode_attention(
     scale: float | None = None,
     k_scale: jax.Array | None = None,  # (num_blocks, Hkv) per-page scales
     v_scale: jax.Array | None = None,
+    layer: jax.Array | int | None = None,  # index into stacked pools
 ) -> jax.Array:
     """Decode attention against the paged KV pool (gather reference).
 
@@ -379,15 +380,18 @@ def paged_decode_attention(
     block-wise without materializing the gathered view in HBM.
 
     With ``k_scale``/``v_scale`` the pools hold quantized codes and the
-    gather dequantizes per (page, kv-head) before attending.
+    gather dequantizes per (page, kv-head) before attending. With ``layer``
+    the pools (and scales) are stacked ``(layers, num_blocks, ...)`` and
+    the gather reads that layer's pages in place.
     """
     b, n_pages = page_table.shape
-    nb, bs, hkv, hd = k_pool.shape
-    k = k_pool[page_table]  # (B, n_pages, bs, hkv, hd)
-    v = v_pool[page_table]
+    bs, hkv, hd = k_pool.shape[-3:]
+    at = (page_table,) if layer is None else (layer, page_table)
+    k = k_pool[at]  # (B, n_pages, bs, hkv, hd)
+    v = v_pool[at]
     if k_scale is not None:
-        k = quant.dequantize(k, k_scale[page_table])
-        v = quant.dequantize(v, v_scale[page_table])
+        k = quant.dequantize(k, k_scale[at])
+        v = quant.dequantize(v, v_scale[at])
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
     k = k.reshape(b, n_pages * bs, hkv, hd)
@@ -402,8 +406,9 @@ def _kv_dtype_of(cache: dict[str, jax.Array]) -> str:
 
 
 def _quant_paged_write(
-    pool: jax.Array,  # (num_blocks, bs, hkv, hd) quantized codes
-    scale_pool: jax.Array,  # (num_blocks, hkv) f32 per-page scales
+    pool: jax.Array,  # (layers, num_blocks, bs, hkv, hd) quantized codes
+    scale_pool: jax.Array,  # (layers, num_blocks, hkv) f32 per-page scales
+    layer: jax.Array,  # the layer written
     rows: jax.Array,  # (B, S, hkv, hd) new full-precision rows
     page: jax.Array,  # (B, S) physical block per row-position
     off: jax.Array,  # (B, S) in-page offset per row-position
@@ -429,15 +434,15 @@ def _quant_paged_write(
         pg = page[:, t]  # (B,)
         ot = off[:, t]  # (B,)
         row = rows[:, t].astype(jnp.float32)  # (B, hkv, hd)
-        old_s = scale_pool[pg]  # (B, hkv)
+        old_s = scale_pool[layer, pg]  # (B, hkv)
         old_eff = jnp.where(ot[:, None] == 0, 0.0, old_s)
         row_s = jnp.max(jnp.abs(row), axis=-1) / quant.qmax(kv_dtype)
         new_s = jnp.maximum(old_eff, row_s)
-        base = quant.dequantize(pool[pg], old_eff)  # (B, bs, hkv, hd)
+        base = quant.dequantize(pool[layer, pg], old_eff)  # (B, bs, hkv, hd)
         merged = base.at[bidx, ot].set(row)
         codes = quant.quantize(merged, new_s, kv_dtype)
-        pool = pool.at[pg].set(codes)
-        scale_pool = scale_pool.at[pg].set(new_s)
+        pool = pool.at[layer, pg].set(codes)
+        scale_pool = scale_pool.at[layer, pg].set(new_s)
     return pool, scale_pool
 
 
@@ -493,8 +498,15 @@ def attention_apply(
     page_table: jax.Array | None = None,  # paged decode: (B, n_pages) int32
     paged_kernel: bool = False,  # paged decode via the Pallas pool kernel
     norm_eps: float = 1e-6,  # the q/k RMSNorm's epsilon
+    layer: jax.Array | None = None,  # paged: the layer of the stacked pools
 ) -> tuple[jax.Array, dict[str, jax.Array] | None]:
-    """Returns (output (B,S,D), updated cache or None)."""
+    """Returns (output (B,S,D), updated cache or None).
+
+    With a ``page_table`` the cache leaves are the model's stacked page
+    pools ``(layers, num_blocks, block_size, hkv, hd)`` (and scales
+    ``(layers, num_blocks, hkv)``): this layer's rows are written at
+    ``[layer, page, offset]`` and read back through the table, so the
+    returned pools are the same buffers updated in place."""
     b, s, d = x.shape
     kv_in = x if kv_source is None else kv_source
 
@@ -522,14 +534,15 @@ def attention_apply(
     new_cache = cache
     if (cur_len is not None and cache is not None and kv_source is None
             and page_table is not None):
-        # Paged decode: the cache leaves are the global page pool
-        # (num_blocks, block_size, hkv, hd).  Row i's s-token block lands at
-        # its slot's positions cur_len..cur_len+s-1 through the page-table
-        # indirection; free slots map to the trash block, so their padding
-        # writes never touch live pages.  Positions past the table (a draft
-        # block's padding tail) are routed to the trash block too — the
-        # engine only ensures pages through each slot's live draft length.
-        nb, bs_pg = cache["k"].shape[0], cache["k"].shape[1]
+        # Paged decode: the cache leaves are the stacked page pools
+        # (layers, num_blocks, block_size, hkv, hd).  Row i's s-token block
+        # lands at its slot's positions cur_len..cur_len+s-1 of this layer
+        # through the page-table indirection; free slots map to the trash
+        # block, so their padding writes never touch live pages.  Positions
+        # past the table (a draft block's padding tail) are routed to the
+        # trash block too — the engine only ensures pages through each
+        # slot's live draft length.
+        bs_pg = cache["k"].shape[2]
         n_pages = page_table.shape[1]
         pos = cur_len[:, None] + jnp.arange(s)[None, :]  # (B, S)
         idx = pos // bs_pg
@@ -543,14 +556,16 @@ def attention_apply(
         if quantized:
             kv_dtype = _kv_dtype_of(cache)
             k_pool, ks_pool = _quant_paged_write(
-                cache["k"], cache["k_scale"], k, page, off, kv_dtype)
+                cache["k"], cache["k_scale"], layer, k, page, off, kv_dtype)
             v_pool, vs_pool = _quant_paged_write(
-                cache["v"], cache["v_scale"], v, page, off, kv_dtype)
+                cache["v"], cache["v_scale"], layer, v, page, off, kv_dtype)
             new_cache = {"k": k_pool, "v": v_pool,
                          "k_scale": ks_pool, "v_scale": vs_pool}
         else:
-            k_pool = cache["k"].at[page, off].set(k.astype(cache["k"].dtype))
-            v_pool = cache["v"].at[page, off].set(v.astype(cache["v"].dtype))
+            k_pool = cache["k"].at[layer, page, off].set(
+                k.astype(cache["k"].dtype))
+            v_pool = cache["v"].at[layer, page, off].set(
+                v.astype(cache["v"].dtype))
             ks_pool = vs_pool = None
             new_cache = {"k": k_pool, "v": v_pool}
         if paged_kernel:
@@ -560,27 +575,29 @@ def attention_apply(
                     out = _kops.paged_attention_quant(
                         q[:, 0], k_pool, v_pool, ks_pool, vs_pool,
                         page_table, cur_len, window=window,
-                        softcap=softcap_val, scale=scale)[:, None]
+                        softcap=softcap_val, scale=scale,
+                        layer=layer)[:, None]
                 else:
                     out = _kops.paged_attention(
                         q[:, 0], k_pool, v_pool, page_table, cur_len,
                         window=window, softcap=softcap_val,
-                        scale=scale)[:, None]
+                        scale=scale, layer=layer)[:, None]
             else:
                 if quantized:
                     out = _kops.paged_attention_multi_quant(
                         q, k_pool, v_pool, ks_pool, vs_pool, page_table,
                         cur_len, window=window, softcap=softcap_val,
-                        scale=scale)
+                        scale=scale, layer=layer)
                 else:
                     out = _kops.paged_attention_multi(
                         q, k_pool, v_pool, page_table, cur_len,
-                        window=window, softcap=softcap_val, scale=scale)
+                        window=window, softcap=softcap_val, scale=scale,
+                        layer=layer)
         else:
             out = paged_decode_attention(
                 q, k_pool, v_pool, page_table, cur_len=cur_len, window=window,
                 softcap_val=softcap_val, scale=scale,
-                k_scale=ks_pool, v_scale=vs_pool)
+                k_scale=ks_pool, v_scale=vs_pool, layer=layer)
     elif cur_len is not None and cache is not None and kv_source is None:
         # Decode: write this step's K/V into the cache (ring-buffered if SWA).
         s_cache = cache["k"].shape[1]
@@ -608,10 +625,11 @@ def attention_apply(
         )
     elif page_table is not None and cache is not None and kv_source is None:
         # Fused prefill -> page scatter (cur_len is None): write this
-        # chunk's K/V projections directly into pool pages through the page
-        # table, then attend with the same streamed flash reference over
-        # the pool context gathered through the table — no contiguous cache
-        # slab, no second jitted scatter.  The chunk's write offsets are
+        # chunk's K/V projections directly into this layer's pool pages
+        # through the page table, then attend with the same streamed flash
+        # reference over the pool context gathered through the table — no
+        # contiguous cache slab, no second jitted scatter.  The chunk's
+        # write offsets are
         # static (q_offset..q_offset+s-1), so the touched logical pages
         # form a static set and the per-page writes unroll page-at-a-time.
         #
@@ -620,7 +638,7 @@ def attention_apply(
         # q_offset + s positions so ``flash_attention_ref`` sees identical
         # shapes (hence an identical block decomposition via
         # ``_pick_chunk``) and identical values — same h, same K/V bits.
-        nb, bs_pg = cache["k"].shape[0], cache["k"].shape[1]
+        bs_pg = cache["k"].shape[2]
         n_pages = page_table.shape[1]
         ctx_len = q_offset + s
         assert n_pages * bs_pg >= ctx_len, "fused prefill needs pages for the full context"
@@ -640,7 +658,7 @@ def attention_apply(
                 for pool, scale_pool, rows in (
                         (k_pool, ks_pool, k), (v_pool, vs_pool, v)):
                     rows = rows[:, lo_t:hi_t].astype(jnp.float32)
-                    old_s = scale_pool[pg]  # (B, hkv)
+                    old_s = scale_pool[layer, pg]  # (B, hkv)
                     # A page starting at offset 0 is fresh: prefill is
                     # append-only from a page-aligned pos0, so a stale
                     # scale from the page's previous owner is ignored.
@@ -651,26 +669,31 @@ def attention_apply(
                                else old_s)
                     new_s = jnp.maximum(old_eff, quant.scales_of(
                         rows, kv_dtype))
-                    base = quant.dequantize(pool[pg], old_eff)
+                    base = quant.dequantize(pool[layer, pg], old_eff)
                     merged = base.at[:, off_lo:off_lo + rows.shape[1]].set(
                         rows)
                     codes = quant.quantize(merged, new_s, kv_dtype)
-                    updates.append((pool.at[pg].set(codes),
-                                    scale_pool.at[pg].set(new_s)))
+                    updates.append((pool.at[layer, pg].set(codes),
+                                    scale_pool.at[layer, pg].set(new_s)))
                 (k_pool, ks_pool), (v_pool, vs_pool) = updates
             new_cache = {"k": k_pool, "v": v_pool,
                          "k_scale": ks_pool, "v_scale": vs_pool}
             k_ctx = quant.dequantize(
-                k_pool[page_table], ks_pool[page_table]).astype(q.dtype)
+                k_pool[layer, page_table],
+                ks_pool[layer, page_table]).astype(q.dtype)
             v_ctx = quant.dequantize(
-                v_pool[page_table], vs_pool[page_table]).astype(q.dtype)
+                v_pool[layer, page_table],
+                vs_pool[layer, page_table]).astype(q.dtype)
         else:
             page = page_table[:, idx_np]  # (B, S) physical blocks
             off = pos_np % bs_pg  # (S,) broadcasts against page
-            k_pool = cache["k"].at[page, off].set(k.astype(cache["k"].dtype))
-            v_pool = cache["v"].at[page, off].set(v.astype(cache["v"].dtype))
+            k_pool = cache["k"].at[layer, page, off].set(
+                k.astype(cache["k"].dtype))
+            v_pool = cache["v"].at[layer, page, off].set(
+                v.astype(cache["v"].dtype))
             new_cache = {"k": k_pool, "v": v_pool}
-            k_ctx, v_ctx = k_pool[page_table], v_pool[page_table]
+            k_ctx = k_pool[layer, page_table]
+            v_ctx = v_pool[layer, page_table]
         hkv = k.shape[2]
         k_ctx = k_ctx.reshape(b, n_pages * bs_pg, hkv, head_dim)[:, :ctx_len]
         v_ctx = v_ctx.reshape(b, n_pages * bs_pg, hkv, head_dim)[:, :ctx_len]

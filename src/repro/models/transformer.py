@@ -280,6 +280,11 @@ def init_cache(cfg: ModelConfig, bsz: int, max_seq: int, *, enc_seq: int | None 
     return cache
 
 
+#: Paged-cache leaves shared by every slot through the page table; any other
+#: leaf (SSM and conv state, cross-attention K/V) has one row per slot.
+PAGED_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+
 def init_paged_cache(
     cfg: ModelConfig, bsz: int, num_blocks: int, block_size: int,
     kv_dtype: str = "fp32",
@@ -350,10 +355,13 @@ def _apply_layer(
     q_offset: int = 0,
     page_table: jax.Array | None = None,
     paged_kernel: bool = False,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, Params, jax.Array, jax.Array | None]:
     """One layer. Returns (h, new_cache, aux_loss, held pairs): the last is
     the (B, S) count of each token's routed experts that this layer holds
-    (None without a served expert layer)."""
+    (None without a served expert layer). With ``layer``, the cache's page
+    pools are the stacked ones and ``layer`` is this layer's index in them
+    (see :func:`forward_hidden`)."""
     aux = jnp.float32(0.0)
     held = None
     new_cache: Params = dict(cache) if cache is not None else None
@@ -385,7 +393,7 @@ def _apply_layer(
             scale=cfg.query_scale, chunk=cfg.attn_chunk, qk_norm=cfg.qk_norm,
             cache=kv_cache, cur_len=cur_len, q_offset=q_offset,
             page_table=page_table, paged_kernel=paged_kernel,
-            norm_eps=cfg.norm_eps)
+            norm_eps=cfg.norm_eps, layer=layer)
         if cfg.sandwich_norm:
             out = norm("post_mixer_norm", out)
         h = residual(resid, out)
@@ -496,41 +504,79 @@ def forward_hidden(
     ``count_pairs`` a fourth item: the (B, S) count, over every layer, of
     each token's routed experts that the layers hold.
 
-    ``page_table`` switches attention layers' decode path to the paged KV
-    pool (cache leaves are then (r, num_blocks, block_size, hkv, hd)); the
-    table itself is shared by every layer, only the pools are per layer.
+    ``page_table`` switches attention layers to the paged KV pool (cache
+    leaves are then (r, num_blocks, block_size, hkv, hd)); the table itself
+    is shared by every layer, only the pools are per layer. The paged
+    caches are then *carried* through the layer loop rather than scanned
+    over: each layer writes its new rows into the stacked pools at its
+    index and reads its pages back through the table (or the kernel's
+    DMAs), and a per-slot leaf's layer is read and written back in place,
+    so a program that is donated the caches updates them where they lie
+    instead of slicing every layer out and stacking a fresh copy.
+    Contiguous caches keep the scanned form: their attention reads a
+    layer's whole cache.
     """
     unit = unit if unit is not None else cfg.layer_unit
     blocks = blocks if blocks is not None else params["blocks"]
     block_caches = caches["blocks"] if caches is not None else None
+    carried = block_caches is not None and page_table is not None
 
-    def body(carry, xs):
-        h, aux, pairs = carry
-        bp, bc = xs
+    def run_layers(h, aux, pairs, bp, bc, layer):
         new_bc = {}
         for i, spec in enumerate(unit):
             lc = bc.get(f"layer{i}") if bc is not None else None
+            if carried:
+                # Per-slot leaves: this layer's rows; page pools: whole.
+                lc = {k: v if k in PAGED_LEAVES
+                      else jax.lax.dynamic_index_in_dim(v, layer, 0, False)
+                      for k, v in lc.items()}
             h, nc, a, held = _apply_layer(
                 cfg, spec, bp[f"layer{i}"], h,
                 positions=positions, cache=lc, cur_len=cur_len,
                 enc_out=enc_out, prefix_len=prefix_len, causal=causal,
                 q_offset=q_offset, page_table=page_table,
-                paged_kernel=paged_kernel)
+                paged_kernel=paged_kernel, layer=layer)
             # Pin activations to batch-sharded layout at layer boundaries so
             # the embedding table's sharding can't flip the whole stack to a
             # replicated-batch TP layout through the scan carry.
             h = meshutil.shard_batch(h)
+            if carried:
+                # A per-slot leaf the layer left alone (cross-attention K/V
+                # in decode) is carried on untouched.
+                stacked = bc[f"layer{i}"]
+                nc = {k: v if k in PAGED_LEAVES
+                      else stacked[k] if v is lc[k]
+                      else jax.lax.dynamic_update_index_in_dim(
+                          stacked[k], v.astype(stacked[k].dtype), layer, 0)
+                      for k, v in nc.items()}
             new_bc[f"layer{i}"] = nc
             aux = aux + a
             if pairs is not None and held is not None:
                 pairs = pairs + held
-        return (h, aux, pairs), new_bc
-
-    body = _remat_wrap(cfg, body)
+        return h, aux, pairs, new_bc
 
     pairs0 = jnp.zeros(h.shape[:2], jnp.int32) if count_pairs else None
-    (h, aux, pairs), new_caches = jax.lax.scan(
-        body, (h, jnp.float32(0.0), pairs0), (blocks, block_caches))
+    if carried:
+        def body(carry, xs):
+            h, aux, pairs, bc = carry
+            bp, layer = xs
+            return run_layers(h, aux, pairs, bp, bc, layer), None
+
+        r = jax.tree.leaves(blocks)[0].shape[0]
+        (h, aux, pairs, new_caches), _ = jax.lax.scan(
+            _remat_wrap(cfg, body),
+            (h, jnp.float32(0.0), pairs0, block_caches),
+            (blocks, jnp.arange(r)))
+    else:
+        def body(carry, xs):
+            h, aux, pairs = carry
+            bp, bc = xs
+            h, aux, pairs, new_bc = run_layers(h, aux, pairs, bp, bc, None)
+            return (h, aux, pairs), new_bc
+
+        (h, aux, pairs), new_caches = jax.lax.scan(
+            _remat_wrap(cfg, body), (h, jnp.float32(0.0), pairs0),
+            (blocks, block_caches))
     out_caches = {"blocks": new_caches} if caches is not None else None
     if count_pairs:
         return h, out_caches, aux, pairs

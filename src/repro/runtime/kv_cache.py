@@ -65,9 +65,6 @@ from repro.models.transformer import ModelConfig
 PREFIX_STORE_SCHEMA = 1
 
 TRASH_PAGE = 0  # physical block 0: sink for padding writes, never allocated
-#: Pool leaves shared by every slot through the page table; any other leaf
-#: (SSM and conv state, cross-attention K/V) has one row per slot on axis 1.
-PAGED_LEAVES = ("k", "v", "k_scale", "v_scale")
 
 # Per-shape jitted scatter/gather/load helpers are cached by page count; an
 # unbounded dict would grow one compile per distinct prefix/evict size over a
@@ -542,6 +539,9 @@ class PagedKVCache:
         self.registry = PrefixRegistry()
         self.pools = T.init_paged_cache(
             cfg, max_batch, num_blocks, block_size, kv_dtype=kv_dtype)
+        #: Registry that counts the runs of programs returning the pools
+        #: (see :meth:`adopt`); the engine installs its own.
+        self.metrics: Any = None
         # Host-side table; pushed to device per decode tick (tiny int32s).
         self.page_table = np.full(
             (max_batch, self.max_pages), TRASH_PAGE, np.int32)
@@ -599,7 +599,7 @@ class PagedKVCache:
         total = 0
         for c in self.pools["blocks"].values():
             for key, leaf in c.items():
-                if key not in PAGED_LEAVES:
+                if key not in T.PAGED_LEAVES:
                     total += leaf.size * leaf.dtype.itemsize // self.max_batch
         return total
 
@@ -724,6 +724,18 @@ class PagedKVCache:
             self.allocator.free(self._owned[slot])
             self._owned[slot] = []
         self.page_table[slot, :] = TRASH_PAGE
+
+    def adopt(self, pools: Any) -> None:
+        """Take ``pools``, what a program that was handed ``self.pools``
+        returned, and count that run: ``serving.pool_donated_runs`` when
+        the program consumed the old buffers (donated, updated in place),
+        ``serving.pool_copied_runs`` when they are still alive beside the
+        new ones (a fresh pool was made). Reads nothing from the device."""
+        donated = jax.tree.leaves(self.pools)[0].is_deleted()
+        self.pools = pools
+        if self.metrics is not None:
+            self.metrics.inc("serving.pool_donated_runs" if donated
+                             else "serving.pool_copied_runs")
 
     @tick_path(allowed_fetches=0)
     def device_page_table(self) -> jax.Array:
@@ -1061,7 +1073,7 @@ class PagedKVCache:
                 out["blocks"][name] = oc
             return out
 
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnums=0)  # the pools, written in place
 
     @transfer_budget(d2h_arrays=0, d2h_outputs=())
     def _make_gather(self, n_pages: int):
@@ -1117,7 +1129,7 @@ class PagedKVCache:
                 out["blocks"][name] = oc
             return out
 
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnums=1)  # the b=1 cache it fills
 
     def scatter(
         self, slot: int, caches: Any, length: int, *, start_page: int = 0
@@ -1136,9 +1148,9 @@ class PagedKVCache:
             "scatter into a shared page would corrupt its sharers", target)
         fn = _lru_jit(self._scatter_jit, n, lambda: self._make_scatter(n),
                       cap=self._jit_cap)
-        self.pools = fn(
+        self.adopt(fn(
             self.pools, caches, jnp.asarray(target, jnp.int32),
-            jnp.int32(slot), jnp.int32(start_page * self.block_size))
+            jnp.int32(slot), jnp.int32(start_page * self.block_size)))
 
     def gather(self, slot: int, length: int) -> Any:
         """Pull ``slot``'s first ``length`` rows out of the pool as a b=1
@@ -1154,7 +1166,8 @@ class PagedKVCache:
     def load_prefix(self, caches: Any, blocks: list[int]) -> Any:
         """Copy ``blocks``' pool rows into the front of a b=1 contiguous
         cache (the prefill context for the uncovered tail of a shared-prefix
-        admission).  Returns the updated cache pytree."""
+        admission).  Returns the updated cache pytree; ``caches`` is
+        donated to it."""
         n = len(blocks)
         assert n > 0
         fn = _lru_jit(self._load_jit, n, lambda: self._make_load(n),
@@ -1177,5 +1190,6 @@ class PagedKVCache:
                     out["blocks"][name] = oc
                 return out
 
-            self._copy_jit = jax.jit(fn)
-        self.pools = self._copy_jit(self.pools, jnp.int32(src), jnp.int32(dst))
+            self._copy_jit = jax.jit(fn, donate_argnums=0)
+        self.adopt(self._copy_jit(self.pools, jnp.int32(src),
+                                  jnp.int32(dst)))

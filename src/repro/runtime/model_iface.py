@@ -201,7 +201,7 @@ class ServableModel:
     # -- decode ----------------------------------------------------------------
 
     @transfer_budget(d2h_arrays=1, d2h_outputs=(0,), d2h_bytes_per_slot=4)
-    def decode_fn(self, *, paged: bool):
+    def decode_fn(self, *, paged: bool, donate: bool = True):
         """The jitted batched decode step with on-device sampling fused in.
 
         Signature matches the engine's tick call: greedy takes
@@ -210,10 +210,15 @@ class ServableModel:
 
         Transfer budget: the tick fetches output 0 — the (B,) int32 of
         sampled tokens — and nothing else (one int32 per slot per tick).
+
+        With ``donate`` the paged steps are donated the pools (argument 2)
+        and update them in place: the caller replaces its pools with the
+        step's.
         """
         cfg = self.cfg
         scfg = self.scfg
         temp = float(scfg.temperature)
+        pools = 2 if donate else ()
 
         def _keys(uids, steps):
             return jax.vmap(serving.slot_key)(uids, steps)
@@ -236,21 +241,23 @@ class ServableModel:
                         T.decode_and_sample_paged(
                             cfg, p, t, c, pt, l, temperature=temp,
                             key=_keys(u, s), paged_kernel=kern,
-                            count_pairs=True), l, a)))
+                            count_pairs=True), l, a), donate_argnums=pools))
             return self._counting(jax.jit(
                 lambda p, t, c, pt, l, a: tally(T.decode_and_sample_paged(
                     cfg, p, t, c, pt, l, paged_kernel=kern,
-                    count_pairs=True), l, a)))
+                    count_pairs=True), l, a), donate_argnums=pools))
         if paged:
             kern = scfg.paged_kernel
             if temp > 0.0:
                 return jax.jit(
                     lambda p, t, c, pt, l, u, s: T.decode_and_sample_paged(
                         cfg, p, t, c, pt, l, temperature=temp,
-                        key=_keys(u, s), paged_kernel=kern))
+                        key=_keys(u, s), paged_kernel=kern),
+                    donate_argnums=pools)
             return jax.jit(
                 lambda p, t, c, pt, l: T.decode_and_sample_paged(
-                    cfg, p, t, c, pt, l, paged_kernel=kern))
+                    cfg, p, t, c, pt, l, paged_kernel=kern),
+                donate_argnums=pools)
         if temp > 0.0:
             return jax.jit(
                 lambda p, t, c, l, u, s: T.decode_and_sample(

@@ -121,7 +121,7 @@ from repro.obs import MetricsRegistry, Tracer
 from repro.kernels import quant
 from repro.models import transformer as T
 from repro.models.transformer import ModelConfig
-from repro.runtime.kv_cache import PAGED_LEAVES, PagedKVCache, _lru_jit
+from repro.runtime.kv_cache import PagedKVCache, _lru_jit
 
 
 @dataclasses.dataclass
@@ -327,7 +327,7 @@ def _slot_rows(pools: Any, slot: jax.Array, *, fresh: bool) -> Any:
     """The pools with each per-slot leaf cut to slot ``slot``'s (b=1) row,
     zeros when ``fresh``; paged leaves pass through whole."""
     def row(key, leaf):
-        if key in PAGED_LEAVES:
+        if key in T.PAGED_LEAVES:
             return leaf
         if fresh:
             return jnp.zeros(leaf.shape[:1] + (1,) + leaf.shape[2:],
@@ -342,7 +342,7 @@ def _put_slot_rows(pools: Any, rows: Any, slot: jax.Array) -> Any:
     """Inverse of :func:`_slot_rows`: each per-slot row written back into
     the pools at ``slot``; paged leaves are taken from ``rows`` whole."""
     def put(key, leaf, new):
-        if key in PAGED_LEAVES:
+        if key in T.PAGED_LEAVES:
             return new
         return jax.lax.dynamic_update_slice_in_dim(
             leaf, new.astype(leaf.dtype), slot, axis=1)
@@ -427,7 +427,8 @@ class ServingEngine:
         in the pool) is read from row ``slot`` (zeros on the first chunk)
         and written back there. Decoder archs only (no prefix embeds /
         encoder output): the engine gates on ``ServeConfig.fused_prefill``,
-        which ``validate_arch`` refuses for whisper.
+        which ``validate_arch`` refuses for whisper. The pools are donated
+        and updated in place.
         """
         key = ("fused", chunk_len, pos0)
 
@@ -449,7 +450,7 @@ class ServingEngine:
                 pools = _put_slot_rows(pools, caches, slot)
                 return T.head_logits(cfg, params, h[:, -1:]), pools
 
-            return jax.jit(fn)
+            return jax.jit(fn, donate_argnums=1)
 
         return _lru_jit(self._chunk_jit, key, make, cap=self._chunk_jit_cap)
 
@@ -812,6 +813,7 @@ class StreamedBatchEngine:
         # scfg.prefix_store (0 = cold start or stale/absent store)
         if self.paged:
             self.kv = self.servable.make_kv_pool()
+            self.kv.metrics = self.metrics
             self.caches = None  # KV lives in self.kv.pools
             if scfg.prefix_sharing and scfg.prefix_store:
                 self.prefixes_restored = self.kv.load_prefixes(
@@ -860,7 +862,14 @@ class StreamedBatchEngine:
         # the jit — see ServableModel.decode_fn): a tick moves one int32
         # per slot to the host, never the (B, vocab) logits.  With
         # temperature, per-slot keys are folded from (uid, step) on device.
-        self._decode_jit = self.servable.decode_fn(paged=self.paged)
+        # A tick dispatches the engine's own step donated the pools
+        # (updated in place); a step put in place of ``_decode_jit`` from
+        # outside (a wrapper, an injected fault) may hand back the pools it
+        # was given, so it is called as it is (see ``_decode_step``).
+        self._decode_jit = self.servable.decode_fn(
+            paged=self.paged, donate=False)
+        self._decode_own = (self._decode_jit,
+                            self.servable.decode_fn(paged=self.paged))
         # Scatter one request's (b=1) cache into slot i of the global cache /
         # gather it back out (contiguous path; the paged engine moves pages
         # through self.kv instead).  Slot index is traced, so one compile
@@ -897,6 +906,8 @@ class StreamedBatchEngine:
         """Install ``registry``; the servable's counters kept on the device
         restart from zero and are read into it at each snapshot."""
         self._metrics = registry
+        if getattr(self, "kv", None) is not None:
+            self.kv.metrics = registry
         servable = getattr(self, "servable", None)
         if servable is not None and servable.device_counters is not None:
             servable.reset_device_counters()
@@ -1087,8 +1098,9 @@ class StreamedBatchEngine:
                 table = jnp.asarray(row[:, :n_ctx])
                 self.obs.add("host", "stage", st0, uid=req.uid)
                 dt0 = self.obs.begin("dispatch")
-                logits, self.kv.pools = fn(
+                logits, pools = fn(
                     self.params, self.kv.pools, table, piece, slot_index)
+                self.kv.adopt(pools)
                 self.obs.add("host", "dispatch", dt0, uid=req.uid)
                 pos += piece.shape[1]
                 n_chunks += 1
@@ -1378,6 +1390,14 @@ class StreamedBatchEngine:
         snap["derived"] = derived
         return snap
 
+    def _decode_step(self):
+        """The decode program a tick dispatches: the engine's own step,
+        donated the pools, or whatever was put in place of
+        ``_decode_jit``, undonated (its runs count as
+        ``serving.pool_copied_runs``)."""
+        own, donated = self._decode_own
+        return donated if self._decode_jit is own else self._decode_jit
+
     @tick_path(allowed_fetches=1)
     def _plain_tick(self) -> None:
         """One batched decode step for all slots (inactive rows are padding).
@@ -1418,9 +1438,9 @@ class StreamedBatchEngine:
             args += [jnp.asarray(uids), jnp.asarray(steps)]
         self.obs.add("host", "stage", st0, tick=tick)
         dt0 = self.obs.begin("dispatch")
-        nxt, new_caches = self._decode_jit(*args)
+        nxt, new_caches = self._decode_step()(*args)
         if self.paged:
-            self.kv.pools = new_caches
+            self.kv.adopt(new_caches)
         else:
             self.caches = new_caches
         self.decode_steps += 1
@@ -1542,7 +1562,7 @@ class StreamedBatchEngine:
         dt0 = self.obs.begin("dispatch")
         emit, n_accept, new_caches = self._spec_jit(*args)
         if self.paged:
-            self.kv.pools = new_caches
+            self.kv.adopt(new_caches)
         else:
             self.caches = new_caches
         self.decode_steps += 1
@@ -1772,9 +1792,10 @@ class StreamedBatchEngine:
         """Time one prefill chunk and one batched decode step (both warmed)
         on synthetic data; the paper's stage-by-stage methodology (§3.3).
 
-        The functional decode step is timed and its result discarded, so the
-        probe never mutates live caches (the padding rows' trash writes stay
-        in the discarded copy).
+        The paged decode step is donated the pools, so the probe puts every
+        row on the trash page at position 0: it writes only there and
+        leaves every slot's state rows alone, and its pools are kept. The
+        contiguous step's result is discarded.
         """
         chunk = min(self.scfg.prefill_chunk, prompt_len)
         toks = jnp.zeros((1, chunk), jnp.int32)
@@ -1788,21 +1809,26 @@ class StreamedBatchEngine:
         t_chunk = time.perf_counter() - t0
 
         b = self.scfg.max_batch
-        dt = jnp.zeros((b, 1), jnp.int32)
-        dl = jnp.zeros((b,), jnp.int32)
-        args = [self.params, dt]
-        if self.paged:
-            args += [self.kv.pools, self.kv.device_page_table()]
-        else:
-            args += [self.caches]
-        args += [dl]
-        if self.scfg.temperature > 0.0:
-            args += [jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32)]
-        out = self._decode_jit(*args)
-        jax.block_until_ready(out[0])
+
+        def decode():
+            zeros = jnp.zeros((b,), jnp.int32)
+            args = [self.params, jnp.zeros((b, 1), jnp.int32)]
+            if self.paged:
+                args += [self.kv.pools,
+                         jnp.zeros_like(self.kv.device_page_table())]
+            else:
+                args += [self.caches]
+            args += [zeros]
+            if self.scfg.temperature > 0.0:
+                args += [zeros, zeros]
+            nxt, caches = self._decode_step()(*args)
+            if self.paged:
+                self.kv.adopt(caches)
+            jax.block_until_ready(nxt)
+
+        decode()
         t0 = time.perf_counter()
-        nxt, _ = self._decode_jit(*args)
-        jax.block_until_ready(nxt)
+        decode()
         t_decode = time.perf_counter() - t0
         self.last_stage_times = rmetric.StageTimes(h2d=t_chunk, kex=t_decode)
         return self.last_stage_times
@@ -1848,6 +1874,7 @@ class StreamedBatchEngine:
                 self.scfg.num_blocks = rows // plan.block_size + 1
             self.scfg.block_size = plan.block_size
             self.kv = self.servable.make_kv_pool()
+            self.kv.metrics = self.metrics
         return plan
 
     def save_prefixes(self) -> int:

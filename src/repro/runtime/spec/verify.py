@@ -148,7 +148,8 @@ def make_verifier(
 
     ``toks[:, 0]`` is each slot's pending token, ``toks[:, 1:]`` the draft
     (zero-padded past ``d_len``); the uids/steps tail exists only at
-    temperature > 0 (per-slot rejection-sampling key streams).
+    temperature > 0 (per-slot rejection-sampling key streams). The paged
+    step is donated the pools and updates them in place.
     """
     temp = float(temperature)
     kern = bool(paged_kernel)
@@ -184,4 +185,4 @@ def make_verifier(
                 emit, n_accept = verify_greedy(logits, toks[:, 1:], d_len)
                 return emit, n_accept, caches
 
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=2 if paged else ())

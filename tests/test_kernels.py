@@ -219,6 +219,70 @@ class TestPagedAttentionBlocks:
             atol=2e-5, rtol=2e-5)
 
 
+class TestPagedAttentionStackedPools:
+    """The model hands the kernel its stacked pools ``(layers, num_blocks,
+    ...)`` and a layer index; the kernel's copies read that layer's pages
+    in place. Each layer holds other values, so a copy from the wrong
+    layer shows. Same blocks and rows as the class above."""
+
+    BS, N_PAGES, P = 4, 10, 3
+    HKV, G, HD = 2, 2, 16
+    LAYERS = 3
+    _blocks_of_p_pages = TestPagedAttentionBlocks._blocks_of_p_pages
+    _case = TestPagedAttentionBlocks._case
+
+    def _stack(self, seed, t, variant):
+        q, kp, vp, pt, cl = self._case(seed, t)
+        ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+        kp = jnp.stack([kp] + [jax.random.normal(k, kp.shape) for k in
+                               jax.random.split(ks[0], self.LAYERS - 1)])
+        vp = jnp.stack([vp] + [jax.random.normal(k, vp.shape) for k in
+                               jax.random.split(ks[1], self.LAYERS - 1)])
+        scales = {}
+        if variant in ("int8", "fp8"):
+            ksc = quant.scales_of(kp, variant)
+            vsc = quant.scales_of(vp, variant)
+            kp = quant.quantize(kp, ksc, variant)
+            vp = quant.quantize(vp, vsc, variant)
+            scales = dict(k_scale=ksc, v_scale=vsc)
+        return q, kp, vp, pt, cl, scales
+
+    @pytest.mark.parametrize("layer", [0, LAYERS - 1])
+    @pytest.mark.parametrize("variant", ["plain", "multi", "int8", "fp8"])
+    def test_matches_model_gather_path(self, variant, layer):
+        t = 3 if variant == "multi" else 1
+        q, kp, vp, pt, cl, scales = self._stack(13, t, variant)
+        qt = q if t > 1 else q[:, None]
+        want = A.paged_decode_attention(qt, kp, vp, pt, cur_len=cl,
+                                        scale=0.25, layer=layer, **scales)
+        kern = (pa.paged_attention_multi_kernel if t > 1
+                else pa.paged_attention_kernel)
+        out = kern(q, kp, vp, pt, cl, scale=0.25, interpret=True,
+                   layer=jnp.int32(layer), **scales)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want if t > 1 else want[:, 0]),
+            atol=2e-5, rtol=2e-5)
+        # The layer's own pools, unstacked, give the same bits.
+        one = {k: v[layer] for k, v in scales.items()}
+        alone = kern(q, kp[layer], vp[layer], pt, cl, scale=0.25,
+                     interpret=True, **one)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(alone))
+
+    @pytest.mark.parametrize("layer", [0, LAYERS - 1])
+    @pytest.mark.parametrize("variant", ["plain", "multi", "int8", "fp8"])
+    def test_ops_vs_oracle(self, variant, layer):
+        t = 3 if variant == "multi" else 1
+        q, kp, vp, pt, cl, scales = self._stack(17, t, variant)
+        name = {"plain": "paged_attention", "multi": "paged_attention_multi",
+                }.get(variant, "paged_attention_quant")
+        args = (q, kp, vp) + ((scales["k_scale"], scales["v_scale"])
+                              if scales else ()) + (pt, cl)
+        out = getattr(ops, name)(*args, scale=0.25, layer=jnp.int32(layer))
+        want = getattr(ref, name + "_ref")(*args, scale=0.25, layer=layer)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
 class TestPagedAttentionMultiKernel:
     """q_len>1 decode variant (speculative verify): per-query causal cut
     inside the draft block, same page stream as the single-token kernel."""
